@@ -3,9 +3,9 @@
 Prints ``name,us_per_call,derived`` rows plus the full result tables, and
 appends one schema-versioned record per bench to ``results/history.jsonl``
 (the bench trajectory ``tools/bench_regress.py`` gates on — DESIGN.md §13).
-Measured on this container's CPU with the small byte-level predictors
-(paper's 1B-14B models scaled down; trends are the claims under test —
-see EXPERIMENTS.md for the claim-by-claim comparison with the paper).
+Runs the small byte-level predictors (the paper's 1B-14B models scaled
+down; trends are the claims under test) on whatever backend JAX finds.
+Rows name no device, so a time from them is not a chip measurement.
 
   PYTHONPATH=src python -m benchmarks.run [--quick] [--only name]
 """
@@ -433,7 +433,9 @@ ALL = [table2_information, table3_traditional, table5_main, fig_chunk_size,
 
 def main() -> None:
     from repro import obs
+    from repro.compile_cache import configure_compile_cache
     from repro.obs.bench_history import BenchHistory, BenchRecord
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)

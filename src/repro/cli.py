@@ -344,6 +344,8 @@ def _cmd_stats(args) -> int:
 
 
 def main(argv=None) -> int:
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
     ap = argparse.ArgumentParser(
         prog="llmc", description="LLM next-token-prediction compressor")
     sub = ap.add_subparsers(dest="cmd", required=True)

@@ -13,7 +13,8 @@ The bridge between the LLM (which emits logits) and the arithmetic coder
   is coded uniformly over the vocabulary (log2 V extra bits). For a
   well-matched predictor on LLM-generated text, escapes are rare, and the
   host coder now touches K+1 integers per token instead of V=151936.
-  The fused TPU kernel for this transform lives in kernels/ac_cdf.py.
+  kernels/ac_cdf.py holds a fused Pallas form of this transform; no
+  coding path dispatches it.
 
 All jnp functions are jit-safe and vmap-able over leading axes.
 """
@@ -79,7 +80,7 @@ def logits_to_cdf(logits, precision: int = DEFAULT_PRECISION) -> np.ndarray:
 def topk_quantized(logits: jnp.ndarray, k: int,
                    precision: int = DEFAULT_PRECISION,
                    temperature: float = 1.0):
-    """Fused (on TPU: see kernels/ac_cdf.py) top-K + escape quantization.
+    """Top-K + escape quantization.
 
     Returns (ids, qpmf):
       ids  int32 (..., k)    — vocabulary ids of the top-k slots
@@ -114,9 +115,7 @@ def topk_cdf(logits: jnp.ndarray, k: int,
     same float computation and the cumsum is exact integer arithmetic
     (2**precision <= 2**23 fits int32), so golden containers are
     unaffected. This is what removes the per-step host-side
-    ``pmf_to_cdf`` slicing from the decode loops; on TPU the same
-    transform runs as the fused Pallas kernel (kernels/ac_cdf.py
-    ``topk_cdf_points``)."""
+    ``pmf_to_cdf`` slicing from the decode loops."""
     ids, q = topk_quantized(logits, k, precision)
     zero = jnp.zeros_like(q[..., :1])
     cdf = jnp.concatenate([zero, jnp.cumsum(q, axis=-1)], axis=-1)

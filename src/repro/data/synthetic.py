@@ -12,6 +12,8 @@ Two kinds of text:
 """
 from __future__ import annotations
 
+import zlib
+
 import numpy as np
 
 _DOMAIN_WORDS = {
@@ -70,7 +72,9 @@ _FILLER = ("and", "of", "to", "in", "a", "is", "that", "it", "with", "as",
 def human_like(domain: str, n_bytes: int, seed: int = 0) -> bytes:
     """Markov-ish procedural text: domain phrases + fillers + punctuation.
     Entropy/byte lands near real English (~4.5 bits char-level)."""
-    rng = np.random.default_rng(seed + hash(domain) % 2**16)
+    # crc32, not hash(): str hashes change from process to process, and
+    # the same seed must give the same text in every run
+    rng = np.random.default_rng(seed + zlib.crc32(domain.encode()) % 2**16)
     words = _DOMAIN_WORDS[domain]
     out = []
     size = 0

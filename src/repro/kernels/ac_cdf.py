@@ -9,8 +9,9 @@ the integer CDF points with a running prefix — two HBM passes total,
 nothing materialized.
 
 Quantization is **cumulative rounding** (see core/cdf.py): strictly
-monotone, exact total, streaming. Grid (B, 2, nv): pass 0 reduces, pass 1
-emits; the pass axis is sequential so scratch carries across.
+monotone, exact total, streaming. Grid (row blocks, 2, nv): pass 0
+reduces, pass 1 emits; the pass axis is sequential so scratch carries
+across. A row block is 8 rows (the TPU's sublane tile) or all of B.
 
 Two kernels share the layout:
 
@@ -21,6 +22,8 @@ Two kernels share the layout:
   emits (ids, cdf) once — the decode loops stop paying a host-side
   ``top_k``/``pmf_to_cdf`` per step.
 
+A vocabulary that ``block_v`` does not divide (151936 = 128 x 1187) ends
+in a partial block, whose lanes past V are masked to -inf in the kernel.
 For padded vocabularies the caller masks pad logits to -inf upstream;
 exp(-inf - max) = 0 contributes nothing and pad symbols get exactly one
 quantum each (they are never coded).
@@ -34,13 +37,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
+from .prefix_sum import prefix_sum
 
 NEG_INF = -1e30
 
 
+def _row_block(B: int) -> int:
+    return 8 if B % 8 == 0 else B
+
+
+def _load_block(logits_ref, j, block_v, V):
+    """This vocab block as f32, lanes past V (a partial last block)
+    masked to NEG_INF."""
+    x = logits_ref[...].astype(jnp.float32)            # (rb, block_v)
+    if V % block_v:
+        gid = j * block_v + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        x = jnp.where(gid < V, x, NEG_INF)
+    return x
+
+
 def _cdf_kernel(logits_ref, out_ref, m_ref, s_ref, c_ref, p_ref, *,
-                block_v, nv, budget):
+                block_v, V, budget):
     p = pl.program_id(1)       # pass: 0 = reduce, 1 = emit
     j = pl.program_id(2)       # vocab block
 
@@ -51,7 +68,7 @@ def _cdf_kernel(logits_ref, out_ref, m_ref, s_ref, c_ref, p_ref, *,
         c_ref[...] = jnp.zeros_like(c_ref)
         p_ref[...] = jnp.zeros_like(p_ref)
 
-    x = logits_ref[0].astype(jnp.float32)              # (1, block_v)
+    x = _load_block(logits_ref, j, block_v, V)
 
     @pl.when(p == 0)
     def _reduce():
@@ -65,7 +82,7 @@ def _cdf_kernel(logits_ref, out_ref, m_ref, s_ref, c_ref, p_ref, *,
     def _emit():
         m, s = m_ref[...], s_ref[...]
         probs = jnp.exp(x - m) / s                     # normalized block pmf
-        cum = c_ref[...] + jnp.cumsum(probs, axis=-1)  # global prefix
+        cum = c_ref[...] + prefix_sum(probs)          # global prefix
         c_ref[...] = cum[:, -1:]
         local = jax.lax.broadcasted_iota(jnp.int32, cum.shape, 1)
         idx = j * block_v + local
@@ -79,12 +96,11 @@ def _cdf_kernel(logits_ref, out_ref, m_ref, s_ref, c_ref, p_ref, *,
         #     force >= prev_last + 1 + local (strictly increasing, and
         #     never above the upper clamp: prev_last <= budget + j*block_v
         #     by the upper clamp of the previous block);
-        #   * tail: the final point is forced to exactly budget + V —
-        #     clamping down (the old code) never pulled a short tail UP.
+        #   * tail: the final point (index V - 1) is forced to exactly
+        #     budget + V — clamping down never pulls a short tail UP.
         pts = jnp.minimum(pts, jnp.int32(budget) + idx + 1)
         pts = jnp.maximum(pts, p_ref[...] + 1 + local)
-        pts = jnp.where((j == nv - 1) & (local == block_v - 1),
-                        jnp.int32(budget) + jnp.int32(nv * block_v), pts)
+        pts = jnp.where(idx == V - 1, jnp.int32(budget) + jnp.int32(V), pts)
         p_ref[...] = pts[:, -1:]
         out_ref[...] = pts
 
@@ -94,32 +110,32 @@ def cdf_points(logits, precision: int, *, block_v=2048, interpret=False):
     prepend 0 on the host for the coder)."""
     B, V = logits.shape
     block_v = min(block_v, V)
-    assert V % block_v == 0
-    nv = V // block_v
+    nv = pl.cdiv(V, block_v)
+    rb = _row_block(B)
     budget = float((1 << precision) - V)
 
-    kernel = functools.partial(_cdf_kernel, block_v=block_v, nv=nv,
+    kernel = functools.partial(_cdf_kernel, block_v=block_v, V=V,
                                budget=budget)
     return pl.pallas_call(
         kernel,
-        grid=(B, 2, nv),
-        in_specs=[pl.BlockSpec((1, block_v), lambda b, p, j: (b, j))],
-        out_specs=pl.BlockSpec((1, block_v), lambda b, p, j: (b, j)),
+        grid=(B // rb, 2, nv),
+        in_specs=[pl.BlockSpec((rb, block_v), lambda b, p, j: (b, j))],
+        out_specs=pl.BlockSpec((rb, block_v), lambda b, p, j: (b, j)),
         out_shape=jax.ShapeDtypeStruct((B, V), jnp.int32),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running sum (scaled)
-            pltpu.VMEM((1, 1), jnp.float32),   # running prefix of cum prob
-            pltpu.VMEM((1, 1), jnp.int32),     # previous block's last point
+            pltpu.VMEM((rb, 1), jnp.float32),  # running max
+            pltpu.VMEM((rb, 1), jnp.float32),  # running sum (scaled)
+            pltpu.VMEM((rb, 1), jnp.float32),  # running prefix of cum prob
+            pltpu.VMEM((rb, 1), jnp.int32),    # previous block's last point
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(logits)
 
 
 def _topk_cdf_kernel(logits_ref, ids_ref, cdf_ref, m_ref, s_ref,
-                     vals_ref, tids_ref, *, block_v, nv, k, budget):
+                     vals_ref, tids_ref, *, block_v, V, k, budget):
     p = pl.program_id(1)       # pass: 0 = reduce + top-k merge, 1 = emit
     j = pl.program_id(2)       # vocab block
 
@@ -130,7 +146,7 @@ def _topk_cdf_kernel(logits_ref, ids_ref, cdf_ref, m_ref, s_ref,
         vals_ref[...] = jnp.full_like(vals_ref, NEG_INF)
         tids_ref[...] = jnp.zeros_like(tids_ref)
 
-    x = logits_ref[...].astype(jnp.float32)            # (1, block_v)
+    x = _load_block(logits_ref, j, block_v, V)
 
     @pl.when(p == 0)
     def _reduce():
@@ -144,7 +160,7 @@ def _topk_cdf_kernel(logits_ref, ids_ref, cdf_ref, m_ref, s_ref,
         # + first-index argmax reproduce lax.top_k's tie rule (smallest
         # vocab id wins): scratch entries carry smaller global ids than
         # this block, and were themselves appended in id order.
-        work = jnp.concatenate([vals_ref[...], x], axis=-1)  # (1, k+block_v)
+        work = jnp.concatenate([vals_ref[...], x], axis=-1)  # (rb, k+bv)
         gid = j * block_v + jax.lax.broadcasted_iota(
             jnp.int32, x.shape, 1)
         wid = jnp.concatenate([tids_ref[...], gid], axis=-1)
@@ -170,18 +186,18 @@ def _topk_cdf_kernel(logits_ref, ids_ref, cdf_ref, m_ref, s_ref,
         # scratch (m, s, top-k) equals the host's flat reduction and the
         # emitted integers are bit-identical to the host path
         m, s = m_ref[...], s_ref[...]
-        top_p = jnp.exp(vals_ref[...] - m) / s                   # (1, k)
+        top_p = jnp.exp(vals_ref[...] - m) / s                   # (rb, k)
         esc = jnp.clip(1.0 - jnp.sum(top_p, axis=-1, keepdims=True),
                        0.0, 1.0)
-        pmf = jnp.concatenate([top_p, esc], axis=-1)             # (1, k+1)
+        pmf = jnp.concatenate([top_p, esc], axis=-1)             # (rb, k+1)
         pmf = pmf / jnp.sum(pmf, axis=-1, keepdims=True)
-        cum = jnp.cumsum(pmf, axis=-1)
+        cum = prefix_sum(pmf)
         cum = cum / cum[:, -1:]
         idx = jax.lax.broadcasted_iota(jnp.int32, cum.shape, 1)
         pts = jnp.floor(cum * budget + 0.5).astype(jnp.int32) + idx + 1
         ids_ref[...] = tids_ref[...]
         cdf_ref[...] = jnp.concatenate(
-            [jnp.zeros_like(pts[:, :1]), pts], axis=-1)          # (1, k+2)
+            [jnp.zeros_like(pts[:, :1]), pts], axis=-1)          # (rb, k+2)
 
 
 def topk_cdf_points(logits, k: int, precision: int, *, block_v=2048,
@@ -198,31 +214,31 @@ def topk_cdf_points(logits, k: int, precision: int, *, block_v=2048,
     """
     B, V = logits.shape
     block_v = min(block_v, V)
-    assert V % block_v == 0
-    nv = V // block_v
+    nv = pl.cdiv(V, block_v)
+    rb = _row_block(B)
     budget = float((1 << precision) - (k + 1))
 
-    kernel = functools.partial(_topk_cdf_kernel, block_v=block_v, nv=nv,
+    kernel = functools.partial(_topk_cdf_kernel, block_v=block_v, V=V,
                                k=k, budget=budget)
     return pl.pallas_call(
         kernel,
-        grid=(B, 2, nv),
-        in_specs=[pl.BlockSpec((1, block_v), lambda b, p, j: (b, j))],
+        grid=(B // rb, 2, nv),
+        in_specs=[pl.BlockSpec((rb, block_v), lambda b, p, j: (b, j))],
         out_specs=[
-            pl.BlockSpec((1, k), lambda b, p, j: (b, 0)),
-            pl.BlockSpec((1, k + 2), lambda b, p, j: (b, 0)),
+            pl.BlockSpec((rb, k), lambda b, p, j: (b, 0)),
+            pl.BlockSpec((rb, k + 2), lambda b, p, j: (b, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, k), jnp.int32),
             jax.ShapeDtypeStruct((B, k + 2), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running sum (scaled)
-            pltpu.VMEM((1, k), jnp.float32),   # running top-k values
-            pltpu.VMEM((1, k), jnp.int32),     # running top-k vocab ids
+            pltpu.VMEM((rb, 1), jnp.float32),  # running max
+            pltpu.VMEM((rb, 1), jnp.float32),  # running sum (scaled)
+            pltpu.VMEM((rb, k), jnp.float32),  # running top-k values
+            pltpu.VMEM((rb, k), jnp.int32),    # running top-k vocab ids
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(logits)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -94,7 +93,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_k=512,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), q4, k_cache, v_cache)

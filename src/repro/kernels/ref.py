@@ -77,16 +77,24 @@ def cdf_quantize_ref(probs_unnorm, precision: int):
     return pts + (1 + jnp.arange(V, dtype=jnp.int32))
 
 
+def _pad_vocab(logits, block_v: int):
+    """f32 logits padded with NEG_INF to whole vocab blocks — the values
+    the kernels mask a partial last block's lanes to."""
+    V = logits.shape[1]
+    pad = -V % block_v
+    return jnp.pad(logits.astype(jnp.float32), ((0, 0), (0, pad)),
+                   constant_values=NEG_INF)
+
+
 def cdf_quantize_blocked_ref(logits, precision: int, block_v: int):
     """Blocked-accumulation oracle for ac_cdf._cdf_kernel: same running
     (max, scaled-sum) softmax, same per-block float prefix carry, same
     exactness clamps — term for term, so the kernel must match it
     BIT-identically (flat vs blocked float cumsum differ by ulps, which
     is why cdf_quantize_ref can only be compared to +-1)."""
-    logits = logits.astype(jnp.float32)
     B, V = logits.shape
-    assert V % block_v == 0
-    nv = V // block_v
+    logits = _pad_vocab(logits, block_v)
+    nv = logits.shape[1] // block_v
     budget = jnp.float32((1 << precision) - V)
     m = jnp.full((B, 1), NEG_INF, jnp.float32)
     s = jnp.zeros((B, 1), jnp.float32)
@@ -108,11 +116,11 @@ def cdf_quantize_blocked_ref(logits, precision: int, block_v: int):
         pts = jnp.floor(cum * budget + 0.5).astype(jnp.int32) + idx + 1
         pts = jnp.minimum(pts, budget.astype(jnp.int32) + idx + 1)
         pts = jnp.maximum(pts, prev + 1 + local)
-        pts = jnp.where((j == nv - 1) & (local == block_v - 1),
+        pts = jnp.where(idx == V - 1,
                         budget.astype(jnp.int32) + jnp.int32(V), pts)
         prev = pts[:, -1:]
         out.append(pts)
-    return jnp.concatenate(out, axis=-1)
+    return jnp.concatenate(out, axis=-1)[:, :V]
 
 
 def topk_cdf_ref(logits, k: int, precision: int):
@@ -142,10 +150,9 @@ def topk_cdf_blocked_ref(logits, k: int, precision: int, block_v: int):
     the kernel's running (max, sum) accumulation and its scratch-first
     k-round extract-max top-k merge, so the multi-block kernel must
     match it bit-identically."""
-    logits = logits.astype(jnp.float32)
-    B, V = logits.shape
-    assert V % block_v == 0
-    nv = V // block_v
+    B = logits.shape[0]
+    logits = _pad_vocab(logits, block_v)
+    nv = logits.shape[1] // block_v
     m = jnp.full((B, 1), NEG_INF, jnp.float32)
     s = jnp.zeros((B, 1), jnp.float32)
     vals = jnp.full((B, k), NEG_INF, jnp.float32)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
+from .prefix_sum import prefix_sum
 
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, s_ref):
@@ -30,7 +30,7 @@ def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, s_ref):
     Q = x.shape[0]
 
     a = dt * A                                 # (Q,1) log decay
-    cum = jnp.cumsum(a, axis=0)                # (Q,1)
+    cum = prefix_sum(a, axis=0)                # (Q,1)
     seg = cum - cum.T                          # (Q,Q) cum_i - cum_j
     ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
@@ -75,7 +75,7 @@ def ssd_intra(x, dt, A, Bm, Cm, *, interpret=False):
             jax.ShapeDtypeStruct((B, H, Q, P), jnp.float32),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(A.astype(jnp.float32), xh, dth, Bm, Cm)

@@ -3,7 +3,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # ^ MUST precede any jax import: jax locks the device count on first init.
 """Multi-pod dry-run: lower + compile every (architecture × input shape)
 cell on the production meshes and record memory / cost / collective
-analysis for EXPERIMENTS.md §Dry-run and §Roofline.
+analysis (results/dryrun/) for the roofline.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3_14b --shape train_4k
@@ -34,6 +34,10 @@ from repro.launch.mesh import make_production_mesh
 from repro.models.schema import abstract_params
 
 RESULTS = pathlib.Path(__file__).resolve().parents[3] / "results" / "dryrun"
+
+# the chip the production meshes are made of (TPU v5e); it keys the
+# roofline's peak rates
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 # long_500k runs only for sub-quadratic archs (SSM / hybrid / SWA);
 # see DESIGN.md §5.
@@ -109,7 +113,7 @@ def lower_cell(arch_id: str, shape_name: str, mesh, *, attn_impl="masked",
     hlo = compiled.as_text()
     model_flops = float(flops_per_token) * n_tokens
     roof = hlo_analysis.roofline_from_compiled(
-        compiled, hlo, n_chips, model_flops)
+        compiled, hlo, n_chips, model_flops, TARGET_DEVICE_KIND)
     coll = hlo_analysis.collective_stats(hlo,
                                          wire_correction=WIRE_CORRECTION)
 
@@ -146,7 +150,7 @@ def lower_cell(arch_id: str, shape_name: str, mesh, *, attn_impl="masked",
 # The probes lower LOOP-FREE programs (scan_layers=False, dense attention,
 # one microbatch, single logits block) at 1-2 layers and reduced batch and
 # extrapolate linearly — every hidden quantity is linear in (layers,
-# microbatches). Caveat recorded in EXPERIMENTS.md: the probes' dense
+# microbatches). Caveat: the probes' dense
 # attention materializes S^2 scores, so the *memory* term is an upper bound
 # for flash-style attention; an analytic score-bytes correction is included.
 
@@ -235,7 +239,7 @@ def probe_roofline(arch_id: str, shape_name: str, mesh,
     Simplified extrapolation: 2 probes in layer count at one microbatch
     size; the whole program scales x num_microbatches. The optimizer
     update is wrongly scaled by that (it runs once per step), a <=2%
-    FLOP error on these models — recorded in EXPERIMENTS.md §Roofline.
+    FLOP error on these models.
     """
     cfg = get_config(arch_id)
     if cfg_extra:
@@ -374,7 +378,7 @@ def run_cells(cells, mesh_kind: str, *, force=False, attn_impl="masked",
                     hlo_flops=pm["flops"] * n_chips,
                     hlo_bytes=pm["bytes"] * n_chips,
                     collective_bytes=pm["coll"],
-                    n_chips=n_chips,
+                    n_chips=n_chips, device_kind=TARGET_DEVICE_KIND,
                     model_flops=res["roofline"]["model_flops"],
                     memory_bytes_analytic=mem_analytic)
                 res["roofline_raw_scanned"] = res["roofline"]

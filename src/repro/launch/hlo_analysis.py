@@ -74,9 +74,28 @@ def collective_stats(hlo_text: str, *, wire_correction: bool = False) -> dict:
 
 
 # ------------------------------------------------------------ roofline terms
-V5E_PEAK_FLOPS = 197e12      # bf16 per chip
-V5E_HBM_BW = 819e9           # bytes/s per chip
-V5E_ICI_BW = 50e9            # bytes/s per link (~per-chip sustained)
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops: float       # bf16 FLOP/s per chip
+    hbm_bw: float      # HBM bytes/s per chip
+    ici_bw: float      # ICI bytes/s per link
+
+
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: "TPU v5 lite" is TPU v5e — Google Cloud documentation, "TPU v5e":
+#: 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI per chip over four
+#: links (50 GB/s each).
+PEAKS = {"TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9)}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip of ``device_kind``; a kind with no published
+    entry is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})") from None
 
 
 def analytic_memory_bytes(cfg, shape, *, n_chips: int, tp: int,
@@ -84,7 +103,7 @@ def analytic_memory_bytes(cfg, shape, *, n_chips: int, tp: int,
     """Per-device HBM traffic model assuming flash-style attention (scores
     stay in VMEM) and fused elementwise chains. Used for the roofline
     memory term because the loop-free probes materialize S^2 scores (an
-    upper bound) — methodology in EXPERIMENTS.md §Roofline.
+    upper bound).
 
     Components (bytes, per device, per step):
       weights     — per-chip weight slice read once per pass
@@ -157,12 +176,16 @@ class Roofline:
     hlo_bytes: float
     collective_bytes: float   # per participant (already per-chip)
     n_chips: int
+    device_kind: str          # key into PEAKS
     model_flops: float = 0.0  # 6·N·D analytic
     memory_bytes_analytic: float = 0.0  # per device, flash-corrected model
 
+    def __post_init__(self):
+        self.peaks = chip_peaks(self.device_kind)
+
     @property
     def t_compute(self) -> float:
-        return self.hlo_flops / (self.n_chips * V5E_PEAK_FLOPS)
+        return self.hlo_flops / (self.n_chips * self.peaks.flops)
 
     @property
     def t_memory(self) -> float:
@@ -170,16 +193,16 @@ class Roofline:
         available (the probe's HLO bytes materialize S^2 attention scores —
         an upper bound reported separately as t_memory_probe)."""
         if self.memory_bytes_analytic:
-            return self.memory_bytes_analytic / V5E_HBM_BW
-        return self.hlo_bytes / (self.n_chips * V5E_HBM_BW)
+            return self.memory_bytes_analytic / self.peaks.hbm_bw
+        return self.hlo_bytes / (self.n_chips * self.peaks.hbm_bw)
 
     @property
     def t_memory_probe(self) -> float:
-        return self.hlo_bytes / (self.n_chips * V5E_HBM_BW)
+        return self.hlo_bytes / (self.n_chips * self.peaks.hbm_bw)
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / V5E_ICI_BW
+        return self.collective_bytes / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -204,7 +227,7 @@ class Roofline:
         t_star = self.t_star
         if t_star == 0:
             return 0.0
-        t_ideal = self.model_flops / (self.n_chips * V5E_PEAK_FLOPS)
+        t_ideal = self.model_flops / (self.n_chips * self.peaks.flops)
         return t_ideal / t_star
 
     def attainment(self, measured_s: float) -> float:
@@ -223,7 +246,8 @@ class Roofline:
         return {
             "hlo_flops": self.hlo_flops, "hlo_bytes": self.hlo_bytes,
             "collective_bytes": self.collective_bytes,
-            "n_chips": self.n_chips, "model_flops": self.model_flops,
+            "n_chips": self.n_chips, "device_kind": self.device_kind,
+            "model_flops": self.model_flops,
             "memory_bytes_analytic": self.memory_bytes_analytic,
             "t_compute_s": self.t_compute, "t_memory_s": self.t_memory,
             "t_memory_probe_s": self.t_memory_probe,
@@ -236,7 +260,7 @@ class Roofline:
 
 
 def roofline_from_compiled(compiled, hlo_text: str, n_chips: int,
-                           model_flops: float) -> Roofline:
+                           model_flops: float, device_kind: str) -> Roofline:
     ca = compiled.cost_analysis()
     if isinstance(ca, list):
         ca = ca[0]
@@ -245,4 +269,4 @@ def roofline_from_compiled(compiled, hlo_text: str, n_chips: int,
     coll = collective_stats(hlo_text)["total_bytes"]
     return Roofline(hlo_flops=flops, hlo_bytes=byts,
                     collective_bytes=float(coll), n_chips=n_chips,
-                    model_flops=model_flops)
+                    device_kind=device_kind, model_flops=model_flops)

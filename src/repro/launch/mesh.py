@@ -13,12 +13,19 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # jax.make_mesh defaults to Explicit axis types; models/layers.shard()
+    # emits with_sharding_constraint, which only accepts Auto axes.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape, axes=None):
@@ -32,12 +39,12 @@ def make_mesh(shape, axes=None):
     if axes is None:
         axes = ("pod", "data", "model")[-len(shape):] if len(shape) <= 3 \
             else tuple(f"ax{i}" for i in range(len(shape)))
-    return jax.make_mesh(shape, tuple(axes))
+    return _auto_mesh(shape, tuple(axes))
 
 
 def local_mesh():
     """Single-device mesh (smoke tests, measured CPU runs)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:

@@ -101,7 +101,11 @@ def train_main(args) -> int:
 
 
 def watchdog(args) -> int:
-    """Respawn the trainer until it exits cleanly (node-failure recovery)."""
+    """Respawn the trainer until it exits cleanly (node-failure recovery).
+
+    This parent must never initialize a JAX backend: a chip belongs to one
+    process, and a parent holding it would make every child trainer fail
+    or hang. All JAX work stays in train_main, which only children run."""
     attempts = 0
     argv = [a for a in sys.argv[1:] if a != "--watchdog"]
     while attempts < args.max_restarts + 1:

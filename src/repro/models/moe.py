@@ -14,8 +14,8 @@ Dispatch design (token-replicated EP, MaxText-flavoured):
   psums partial outputs over 'model'.
 * Communication per layer = FSDP weight all-gather + one psum over
   'model' — there is **no all-to-all**; the trade is E-way routing compute
-  replication (router is D*E, negligible). An a2a variant is a recorded
-  perf-iteration candidate (EXPERIMENTS.md §Perf).
+  replication (router is D*E, negligible). An a2a variant is a
+  perf-iteration candidate.
 
 The same routine with tp=1 is the single-device reference path used in
 smoke tests and as the oracle for the distributed test.

@@ -5,7 +5,8 @@ implements core.compressor.PredictorAdapter over any model-zoo config:
 
   * score_chunks — one jitted teacher-forced forward over (B, C) chunks
     (prefill-shaped; on the production mesh this is the pjit `score_step`).
-  * decode loop — jitted single-token step with a donated cache.
+  * decode loop — jitted single-token step. The cache is not donated:
+    each step writes a new cache beside the one it read.
 
 The BOS convention: the model input for chunk tokens x_0..x_{C-1} is
 [BOS, x_0, .., x_{C-2}], so logits[t] parameterizes P(x_t | x_<t) with a

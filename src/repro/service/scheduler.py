@@ -418,8 +418,8 @@ class SlotScheduler:
             tq = self._t % self.C
             truth = self._tok_buf[self._lanes, tq]
             if self.topk:
-                # fused device top-k -> quantized CDF (kernels/ac_cdf.py on
-                # TPU): no host pmf cumsum per step; same integers
+                # XLA top-k -> quantized CDF on the device: no host pmf
+                # cumsum per step; same integers as the host quantizer
                 ids, cdfs = topk_cdf_jit(logits, self.topk, self.precision)
                 ids = np.asarray(ids)
                 cdfs = np.asarray(cdfs, np.int64)                # (B, K+2)

@@ -444,7 +444,7 @@ def test_bench_regress_separates_quick_and_full(tmp_path):
 def test_roofline_t_star_and_attainment():
     from repro.launch.hlo_analysis import Roofline
     r = Roofline(hlo_flops=1e12, hlo_bytes=1e9, collective_bytes=0.0,
-                 n_chips=1)
+                 n_chips=1, device_kind="TPU v5 lite")
     assert r.t_star == pytest.approx(
         max(r.t_compute, r.t_memory, r.t_collective))
     assert r.attainment(r.t_star * 2) == pytest.approx(0.5)
@@ -453,6 +453,18 @@ def test_roofline_t_star_and_attainment():
     assert r.attainment(None) == 0.0
     assert r.attainment(0.0) == 0.0
     assert r.to_dict()["t_star_s"] == pytest.approx(r.t_star)
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    from repro.launch.hlo_analysis import Roofline, chip_peaks
+    assert chip_peaks("TPU v5 lite").flops == 197e12
+    assert chip_peaks("TPU v5 lite").hbm_bw == 819e9
+    # an unknown chip is an error, never v5e's numbers
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        Roofline(hlo_flops=1e12, hlo_bytes=1e9, collective_bytes=0.0,
+                 n_chips=1, device_kind="TPU v4")
 
 
 def test_attainment_rows_from_stored_cells():

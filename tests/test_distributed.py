@@ -1,5 +1,6 @@
 """Multi-device correctness (8 host devices in a subprocess — the parent
 test process must keep seeing 1 device)."""
+import os
 import pathlib
 import subprocess
 import sys
@@ -11,8 +12,12 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def _run(code: str, timeout=900):
-    env = {"PYTHONPATH": f"{REPO}/src:{REPO}", "HOME": "/root",
+    # the 8 devices are host devices: the child stays on the CPU backend
+    # and never starts the TPU runtime
+    env = {"PYTHONPATH": f"{REPO}/src:{REPO}",
+           "HOME": os.environ.get("HOME", str(REPO)),
            "PATH": "/usr/bin:/bin",
+           "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
            "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,

@@ -1,4 +1,5 @@
 """Checkpoint/restart, corruption fallback, bitwise resume, watchdog."""
+import os
 import pathlib
 import subprocess
 import sys
@@ -94,7 +95,8 @@ def test_watchdog_restart_end_to_end(tmp_path):
            "--ckpt-dir", str(tmp_path), "--ckpt-every", "5",
            "--crash-at", "7", "--watchdog", "--log-every", "5"]
     env = {"PYTHONPATH": f"{REPO}/src", "PATH": "/usr/bin:/bin",
-           "HOME": "/root"}
+           "HOME": os.environ.get("HOME", str(REPO)),
+           "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
     out = subprocess.run(cmd, capture_output=True, text=True, env=env,
                          timeout=600)
     assert "train.fault_injection" in out.stdout

@@ -82,6 +82,7 @@ def test_ssd_intra(B, Q, H, P, N):
 @pytest.mark.parametrize("B,V,bv,prec", [
     (4, 256, 64, 16), (2, 1024, 256, 16), (1, 512, 512, 14),
     (3, 4096, 1024, 18),
+    (16, 1000, 256, 16),     # two row blocks of 8, partial last vocab block
 ])
 def test_cdf_points(B, V, bv, prec):
     lg = jnp.asarray(RNG.normal(size=(B, V)) * 3, jnp.float32)
@@ -97,6 +98,7 @@ def test_cdf_points(B, V, bv, prec):
 @pytest.mark.parametrize("B,V,bv,prec", [
     (4, 256, 64, 16), (2, 1024, 256, 16), (1, 512, 512, 14),
     (3, 4096, 1024, 18),
+    (16, 1000, 256, 16),     # two row blocks of 8, partial last vocab block
 ])
 def test_cdf_points_bitwise_vs_blocked_oracle(B, V, bv, prec):
     """The kernel's blocked float accumulation is replayed term-for-term
@@ -159,6 +161,7 @@ def test_cdf_points_kernel_vs_host_property(seed, bv, prec):
 
 @pytest.mark.parametrize("B,V,k,prec", [
     (4, 512, 16, 16), (2, 1024, 48, 16), (1, 256, 8, 14),
+    (16, 1000, 48, 16),
 ])
 def test_topk_cdf_single_block_bitwise_vs_host(B, V, k, prec):
     """With one vocab block the fused kernel's reductions are the host's
@@ -179,6 +182,7 @@ def test_topk_cdf_single_block_bitwise_vs_host(B, V, k, prec):
 
 @pytest.mark.parametrize("B,V,k,bv,prec", [
     (4, 512, 16, 128, 16), (2, 1024, 32, 256, 16), (3, 512, 8, 64, 14),
+    (16, 1000, 32, 256, 16),
 ])
 def test_topk_cdf_blocked_bitwise_and_invariants(B, V, k, bv, prec):
     lg = jnp.asarray(RNG.normal(size=(B, V)) * 3, jnp.float32)
