@@ -176,9 +176,10 @@ def run_overhead(n_jobs=24, slots=8, chunk=32, topk=8, repeats=5, seed=0,
     workload through two services — registry enabled vs disabled —
     interleaved, min-of-repeats (min is the noise-robust estimator for a
     deterministic workload) — plus a third leg with a timeline recorder
-    installed (DESIGN.md §13: every-step scheduler spans + event ring
-    writes). Decoded tokens are compared against the originals every
-    repeat on all legs: telemetry must never change output bytes.
+    installed (DESIGN.md §13: one event-ring write per span; the per-step
+    spans themselves run in the enabled leg too). Decoded tokens are
+    compared against the originals every repeat on all legs: telemetry
+    must never change output bytes.
     Budgets: enabled <= disabled * (1 + 2%) + 10ms absolute slack;
     recording <= *enabled* * (1 + 10%) + the same slack — the recorder
     requires the registry, so its budget bounds the marginal cost of

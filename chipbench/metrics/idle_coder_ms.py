@@ -1,0 +1,8 @@
+"""Device idle milliseconds per service step of the traced stretch while
+the host was in the ``coder`` bucket of ``chipbench/spans.py`` (the
+innermost program span open over each idle piece decides it)."""
+from chipbench.spans import idle_ms
+
+
+def read(rec):
+    return idle_ms(rec, "coder")

@@ -114,7 +114,13 @@ class Histogram:
         v = float(v)
         self.count += 1
         self.sum += v
-        self.counts[self.bucket_index(v)] += 1
+        # bucket_index inlined: spans observe on every scheduler step
+        if v <= 0.0:
+            self.counts[0] += 1
+            return
+        e = math.frexp(v)[1]
+        e = _EXP_LO if e < _EXP_LO else _EXP_HI if e > _EXP_HI else e
+        self.counts[e - _EXP_LO + 1] += 1
 
     def observe_many(self, values) -> None:
         for v in values:

@@ -22,7 +22,8 @@ DESIGN.md §13. Three layers on top of the span vocabulary of
 
 * ``PhaseReport`` — rolls span events up into a per-job wall-time
   breakdown: **exclusive** seconds (child-span time subtracted) per
-  phase — model / coder / scheduler / router / prefix_cache / other —
+  phase — model / transfer / cdf / coder / scheduler / router /
+  prefix_cache / other —
   plus an ``unattributed`` residual so the phases always sum to the
   report's total wall. ``PhaseReport.from_events`` attributes a
   ``[t0, t1]`` window (a job's submit→done interval, clipping events at
@@ -44,6 +45,8 @@ from typing import Optional
 #: model time, and the step span's exclusive time is scheduler time.
 PHASE_PREFIXES = (
     ("model", ("model.",)),
+    ("transfer", ("transfer.",)),
+    ("cdf", ("cdf.",)),
     ("coder", ("coder.", "rans.", "compress.encode", "decode.coder")),
     ("router", ("router.", "compress.route")),
     ("prefix_cache", ("prefix_cache.",)),
@@ -195,8 +198,7 @@ def uninstall() -> Optional[TimelineRecorder]:
 
 
 def active() -> Optional[TimelineRecorder]:
-    """The installed recorder, or None. Hot paths may consult this to
-    stop sampling spans (record every step) while a timeline is live."""
+    """The installed recorder, or None."""
     return _recorder
 
 
@@ -315,8 +317,6 @@ def phases_from_registry(reg) -> dict:
     """Phase -> exclusive seconds from the ``span.<path>.seconds``
     histograms alone (no recorder needed). The nesting path IS the tree:
     a path's exclusive time is its sum minus its direct children's sums.
-    Sampled spans (scheduler step 1-in-N) under-count proportionally —
-    this is the cheap trajectory signal, the recorder is the precise one.
     """
     sums: dict = {}
     for name, m in getattr(reg, "_metrics", {}).items():

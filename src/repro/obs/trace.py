@@ -7,6 +7,12 @@ path (``service.step/model.decode_step``), built from a thread-local
 span stack, so one histogram exists per distinct call *position*, not
 just per label.
 
+A span opened with no registry records into the registry of the
+innermost open span on its thread, else into the process default
+(``current_registry()``; counters of code that takes no registry use
+the same rule). So the model's and the coder's spans under a service
+step land in the service's registry, next to the step that called them.
+
 When JAX is in the process, every span also enters a
 ``jax.profiler.TraceAnnotation`` with the same label, so capturing a
 device profile (XProf/Perfetto) shows the host spans interleaved with
@@ -17,9 +23,8 @@ session is active; mirroring can still be forced off with
 mirror activates only if something else already imported jax.
 
 Spans follow the registry switch: ``span()`` returns a shared null
-context manager when the target registry (argument, else the process
-default) is disabled, so a disabled process pays one attribute check
-per span site.
+context manager when the target registry is disabled, so a disabled
+registry pays one attribute check per span site.
 
 When a ``obs.timeline.TimelineRecorder`` is installed, every closing
 span additionally appends one event (name, path, start, duration,
@@ -39,6 +44,9 @@ _tls = threading.local()
 _enabled = True          # module master switch (obs.trace.enable(False))
 _jax_mirror = True       # mirror into jax.profiler.TraceAnnotation
 _TraceAnnotation = None  # resolved lazily; False = unavailable
+_compiles = 0            # backend compiles + persistent-cache loads seen
+_compiles_lock = threading.Lock()   # compiles may finish on any thread
+_listening = False       # the jax.monitoring listener is registered
 
 
 def enable(on: bool = True) -> None:
@@ -61,7 +69,37 @@ def _stack() -> list:
 def current() -> str:
     """Slash-joined path of the innermost open span ('' outside spans)."""
     s = _stack()
-    return "/".join(s) if s else ""
+    return s[-1].path if s else ""
+
+
+def current_registry():
+    """The registry of the innermost open span on this thread, else the
+    process default: where a span or counter with no explicit registry
+    records."""
+    s = _stack()
+    return s[-1].reg if s else _metrics.registry()
+
+
+def _on_compile(event, duration, **_):
+    global _compiles
+    if event == "/jax/core/compile/backend_compile_duration":
+        with _compiles_lock:
+            _compiles += 1
+
+
+def compile_count() -> int:
+    """Backend compiles in this process, persistent-cache loads included
+    (JAX times both as one backend compile), counted by one
+    ``jax.monitoring`` listener registered at the first call made after
+    jax is imported; 0 before that."""
+    global _listening
+    if not _listening and "jax" in sys.modules:
+        with _compiles_lock:
+            if not _listening:
+                from jax import monitoring
+                monitoring.register_event_duration_secs_listener(_on_compile)
+                _listening = True
+    return _compiles
 
 
 def _resolve_jax():
@@ -90,29 +128,30 @@ class _NullSpan:
 
 _NULL = _NullSpan()
 
-#: shared no-op span — for call sites that sample their own spans
-#: (e.g. the scheduler times every Nth step) and need the "not this
-#: time" branch to cost one attribute read
+#: shared no-op span, for call sites that decide themselves not to time
+#: a region and need the "not this time" branch to cost nothing
 NULL = _NULL
 
 
 class Span:
-    __slots__ = ("name", "_reg", "_t0", "_jax", "path", "tags", "_mirror")
+    __slots__ = ("name", "reg", "_observe", "_t0", "_jax", "path", "tags",
+                 "_stack")
 
-    def __init__(self, name: str, reg, tags=None, mirror=True):
+    def __init__(self, name: str, reg, observe: bool = True, tags=None):
         self.name = name
-        self._reg = reg
+        self.reg = reg
+        self._observe = observe
         self._jax = None
         self.path = name
         self.tags = tags
-        self._mirror = mirror
 
     def __enter__(self):
-        stack = _stack()
-        stack.append(self.name)
-        self.path = "/".join(stack)
-        if _jax_mirror and self._mirror:
-            ta = _resolve_jax()
+        stack = self._stack = _stack()
+        if stack:
+            self.path = stack[-1].path + "/" + self.name
+        stack.append(self)
+        if _jax_mirror:
+            ta = _TraceAnnotation or _resolve_jax()
             if ta:
                 self._jax = ta(self.name)
                 self._jax.__enter__()
@@ -123,9 +162,9 @@ class Span:
         dt = time.perf_counter() - self._t0
         if self._jax is not None:
             self._jax.__exit__(*exc)
-        _stack().pop()
-        if self._reg is not None:
-            self._reg.histogram(
+        self._stack.pop()
+        if self._observe:
+            self.reg.histogram(
                 "span." + self.path + ".seconds",
                 "wall seconds spent in this span path").observe(dt)
         rec = _timeline._recorder
@@ -134,27 +173,24 @@ class Span:
         return False
 
 
-def span(name: str, registry=None, tags=None, mirror=True):
-    """Open a traced region. Records into ``registry`` (default: the
-    process-global one). Returns a shared null context manager when
+def span(name: str, registry=None, tags=None):
+    """Open a traced region. Records into ``registry``, else into the
+    innermost open span's registry, else the process-global one
+    (``current_registry()``). Returns a shared null context manager when
     tracing or the target registry is disabled. ``tags`` (e.g.
     ``{"job": 3, "chunk": 7}``) ride along on timeline events only —
-    they never fan out histogram names. ``mirror=False`` skips the
-    jax.profiler.TraceAnnotation mirror for per-step hot-loop spans
-    whose TraceMe cost would dominate the region they time.
+    they never fan out histogram names.
 
     A process-wide timeline recorder (obs.timeline.install) overrides
     the registry gate: spans still land on the timeline even when their
-    target registry is disabled or is not the recording service's own —
-    the recorder is process-scoped, so the timeline must see every span
-    in the process (a service's private registry would otherwise hide
-    the coder/model spans that record against the global one). Such
-    timeline-only spans skip the histogram observe."""
+    target registry is disabled — the recorder is process-scoped, so the
+    timeline must see every span in the process. Such timeline-only
+    spans skip the histogram observe."""
     if not _enabled:
         return _NULL
-    reg = registry if registry is not None else _metrics.registry()
+    reg = registry if registry is not None else current_registry()
     if reg.enabled:
-        return Span(name, reg, tags, mirror)
+        return Span(name, reg, True, tags)
     if _timeline._recorder is None:
         return _NULL
-    return Span(name, None, tags, mirror)   # timeline-only span
+    return Span(name, reg, False, tags)     # timeline-only span

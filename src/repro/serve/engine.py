@@ -29,6 +29,14 @@ from repro.configs.base import ModelConfig
 from repro.models import api as model_api
 
 
+def _count_bytes(counter: str, host: np.ndarray) -> None:
+    """Add a host array's bytes to ``counter`` in the current registry
+    (the open span's, else the process default) while it is enabled."""
+    reg = obs.trace.current_registry()
+    if reg.enabled:
+        reg.counter(counter).inc(host.nbytes)
+
+
 class ModelPredictor:
     """PredictorAdapter over the model zoo (single-host execution)."""
 
@@ -257,11 +265,18 @@ class ModelPredictor:
         self._decode_max_len = int(n)
 
     def decode_step(self, state, prev_tokens: np.ndarray):
-        with obs.span("model.decode_step"):
-            logits, state = self._decode(self.params, state,
-                                         jnp.asarray(prev_tokens, jnp.int32),
-                                         self.extra_batch)
-            return np.asarray(logits), state
+        """One decode step: (B,) previous tokens -> (B, V) logits on the
+        host and the next state. The wait for the program ends before
+        ``transfer.logits_to_host`` opens, so that span times the copy."""
+        prev = np.asarray(prev_tokens, np.int32)
+        _count_bytes("transfer.h2d_bytes", prev)
+        logits, state = self._decode(self.params, state, jnp.asarray(prev),
+                                     self.extra_batch)
+        logits.block_until_ready()
+        with obs.span("transfer.logits_to_host"):
+            logits = np.asarray(logits)
+        _count_bytes("transfer.d2h_bytes", logits)
+        return logits, state
 
     def verify_steps(self, state, seq: np.ndarray):
         """Speculative-decode verify program: score seq (B, T) — column 0
@@ -305,7 +320,9 @@ class ModelPredictor:
         scheduler (repro.service). One jitted call, no recompilation:
         the mask is a runtime input."""
         with obs.span("model.reset_slots"):
-            return self._reset(state, jnp.asarray(mask, bool))
+            mask = np.asarray(mask, bool)
+            _count_bytes("transfer.h2d_bytes", mask)
+            return self._reset(state, jnp.asarray(mask))
 
     # ----------------------------------------------------------- sampling
     def generate(self, n_tokens: int, batch: int = 1, *, temperature=1.0,

@@ -33,9 +33,19 @@ Telemetry (DESIGN.md §10): the scheduler owns a ``MetricsRegistry``
 (``scheduler.model_steps`` …) are ALWAYS maintained — ``SchedulerStats``
 is now a thin attribute view over them — while everything optional
 (per-slot code-length accrual for chunk diagnostics, the
-``chunk.bits_per_token`` histogram, step spans, periodic progress lines)
-is gated on ``registry.enabled``, and none of it can change output
-bytes: every telemetry read happens *after* the coder ops it describes.
+``chunk.bits_per_token`` histogram, step spans, the transfer and
+compile counters, periodic progress lines) is gated on
+``registry.enabled``, and none of it can change output bytes: every
+telemetry read happens *after* the coder ops it describes.
+
+Every step opens the same spans, each once: ``service.step`` around it
+all, ``service.refill`` when slots are refilled, ``model.decode_step``
+around the model call up to the logits on the host (the predictor's
+``transfer.logits_to_host`` nests in it), ``cdf.build`` around the CDF
+program and the fetch of its ids and CDFs, ``coder.step`` around the
+host coder, and ``service.finish_slot`` (with ``rans.flush_slot``) per
+finished slot. Spans and counters the predictor and the coder open
+without a registry land in the scheduler's (``obs.trace``).
 """
 from __future__ import annotations
 
@@ -146,11 +156,6 @@ class SlotScheduler:
     #: (0 disables; only when the registry is enabled)
     log_every = 4096
 
-    #: time one full ``service.step`` span every N model steps (sampled:
-    #: a per-step span costs more than the whole telemetry budget on a
-    #: model-free predictor; the histogram notes the sampling rate)
-    span_every = 16
-
     def __init__(self, predictor, *, n_slots: int, chunk_size: int,
                  topk: int = 0, precision: int = DEFAULT_PRECISION,
                  registry: MetricsRegistry | None = None,
@@ -225,10 +230,13 @@ class SlotScheduler:
         self._c_prefill = self.registry.counter("scheduler.prefill_steps")
         self._h_bpt = self.registry.histogram(
             "chunk.bits_per_token", "realized payload bits/token per chunk")
-        self._h_step = self.registry.histogram(
-            "span.service.step.seconds",
-            f"wall seconds per scheduler step (1-in-{self.span_every} "
-            f"sampled; every step while a timeline recorder is installed)")
+        self._c_d2h = self.registry.counter(
+            "transfer.d2h_bytes", "bytes of arrays fetched to the host")
+        self._c_h2d = self.registry.counter(
+            "transfer.h2d_bytes", "bytes of host arrays passed to programs")
+        self._c_compiles = self.registry.counter(
+            "scheduler.step_compiles",
+            "backend compiles and compile-cache loads inside a step")
         # per-slot diagnostics accrual (registry.enabled only). Decode
         # lanes: the coder's interval freq for position t lands in
         # _fbuf[b, t] (one fancy write per step, all log2 math deferred
@@ -303,12 +311,9 @@ class SlotScheduler:
         free = np.nonzero(~self._active)[0]
         if not free.size or not self._queue:
             return
-        # timeline-only span (DESIGN.md §13): placed after the idle early-
-        # out so it marks productive refills, not every step's free-slot
-        # check — the recording leg's overhead budget is 10%
-        sp = obs.span("service.refill", self.registry, mirror=False) \
-            if obs.timeline.active() is not None else obs.trace.NULL
-        with sp:
+        # after the idle early-out: the span marks productive refills,
+        # not every step's free-slot check
+        with obs.span("service.refill", self.registry):
             self._refill_slots(free)
 
     def _refill_slots(self, free) -> None:
@@ -338,10 +343,7 @@ class SlotScheduler:
                 can_cache = (self.prefix_cache is not None
                              and hasattr(self.predictor, "restore_slot"))
                 if can_cache and getattr(task, "cacheable", False):
-                    with obs.span("prefix_cache.lookup", self.registry,
-                                  mirror=False) \
-                            if obs.timeline.active() is not None \
-                            else obs.trace.NULL:
+                    with obs.span("prefix_cache.lookup", self.registry):
                         matched, snap = self.prefix_cache.lookup(ctx)
                     if matched:
                         # resume from the stored post-prefill state: the
@@ -384,46 +386,53 @@ class SlotScheduler:
     def step(self) -> bool:
         """One fixed-shape model step + one coder step over all active
         slots. Returns False when there was nothing to do."""
+        if self.idle:
+            return False
         tel = self.registry.enabled
-        # a live timeline recorder lifts the 1-in-N span sampling: phase
-        # attribution needs every step on the timeline (≥90% coverage),
-        # and the recording leg has its own ≤10% overhead budget
-        rec = obs.timeline.active()
-        sp = obs.span("service.step", self.registry,
-                      mirror=rec is None) \
-            if rec is not None or (tel and self.span_every
-                                   and self._c_steps.value
-                                   % self.span_every == 0) else obs.trace.NULL
-        with sp:
-            self._ensure_state()
-            self._refill()
-            m = self._active
-            if not m.any():
-                return False
-            # model phase attribution: only worth a span while a timeline
-            # is recording (serve/steps.py predictors carry their own
-            # model.* spans; plain predictors would otherwise attribute
-            # model time to the scheduler)
-            msp = obs.span("model.decode_step", self.registry,
-                           mirror=False) \
-                if rec is not None else obs.trace.NULL
-            with msp:
-                logits, self._state = self.predictor.decode_step(
-                    self._state, self._prev)
-                logits = np.asarray(logits)
-            pm = m & (self._cpos < self._ctxlen)     # prefilling context
-            am = m & ~pm                             # coding this step
-            dm = am & self._is_dec
-            cm = am & ~self._is_dec
-            tq = self._t % self.C
-            truth = self._tok_buf[self._lanes, tq]
-            if self.topk:
-                # XLA top-k -> quantized CDF on the device: no host pmf
-                # cumsum per step; same integers as the host quantizer
+        if tel:
+            compiles = obs.trace.compile_count()
+        with obs.span("service.step", self.registry):
+            self._step(tel)
+        if tel:
+            compiles = obs.trace.compile_count() - compiles
+            if compiles:
+                self._c_compiles.inc(compiles)
+                obs.log("scheduler.recompiled", step=self._c_steps.value,
+                        compiles=compiles)
+            if self.log_every and self._c_steps.value % self.log_every == 0:
+                obs.log("scheduler.progress", steps=self._c_steps.value,
+                        occupancy=round(self.stats.occupancy, 4),
+                        chunks=self._c_chunks.value,
+                        queued=len(self._queue),
+                        failures=self._c_failures.value)
+        return True
+
+    def _step(self, tel: bool) -> None:
+        self._ensure_state()
+        self._refill()           # not idle: some slot is active after it
+        m = self._active
+        with obs.span("model.decode_step", self.registry):
+            logits, self._state = self.predictor.decode_step(
+                self._state, self._prev)
+            logits = np.asarray(logits)
+        pm = m & (self._cpos < self._ctxlen)     # prefilling context
+        am = m & ~pm                             # coding this step
+        dm = am & self._is_dec
+        cm = am & ~self._is_dec
+        tq = self._t % self.C
+        truth = self._tok_buf[self._lanes, tq]
+        syms = np.zeros(self.B, np.int64)
+        if self.topk:
+            # XLA top-k -> quantized CDF on the device: no host pmf
+            # cumsum per step; same integers as the host quantizer
+            with obs.span("cdf.build", self.registry):
                 ids, cdfs = topk_cdf_jit(logits, self.topk, self.precision)
-                ids = np.asarray(ids)
-                cdfs = np.asarray(cdfs, np.int64)                # (B, K+2)
-                syms = np.zeros(self.B, np.int64)
+                ids, cdfs = np.asarray(ids), np.asarray(cdfs)   # (B, K+2)
+                if tel:
+                    self._c_h2d.inc(logits.nbytes)
+                    self._c_d2h.inc(ids.nbytes + cdfs.nbytes)
+                cdfs = cdfs.astype(np.int64)
+            with obs.span("coder.step", self.registry):
                 if dm.any():
                     slots = self._dec.get(cdfs, self.precision, dm)
                     if tel:   # coder-computed interval freqs, one write
@@ -453,10 +462,14 @@ class SlotScheduler:
                         self._c_escapes.inc(int(em.sum()))
                         if tel:
                             self._nesc[em] += 1
-            else:
-                cdfs = np.asarray(full_cdf_jit(logits, self.precision),
-                                  np.int64)                       # (B, V+1)
-                syms = np.zeros(self.B, np.int64)
+        else:
+            with obs.span("cdf.build", self.registry):
+                cdfs = np.asarray(full_cdf_jit(logits, self.precision))
+                if tel:                                         # (B, V+1)
+                    self._c_h2d.inc(logits.nbytes)
+                    self._c_d2h.inc(cdfs.nbytes)
+                cdfs = cdfs.astype(np.int64)
+            with obs.span("coder.step", self.registry):
                 if dm.any():
                     syms = self._dec.get(cdfs, self.precision, dm)
                     if tel:
@@ -464,50 +477,39 @@ class SlotScheduler:
                 if cm.any():
                     self._enc.put_symbols(truth.astype(np.int64), cdfs,
                                           self.precision, cm)
-            # write decoded tokens; advance every coding lane. Prefill
-            # lanes feed their next context token instead — their logits
-            # this step are discarded (context conditioning only).
-            nxt = np.where(dm, syms, truth).astype(np.int32)
-            for b in np.nonzero(pm)[0]:
-                nxt[b] = self._ctx[b][self._cpos[b]]
-            self._tok_buf[dm, self._t[dm]] = nxt[dm]
-            self._prev = np.where(m, nxt, self._prev).astype(np.int32)
-            self._t[am] += 1
-            self._cpos[pm] += 1
-            self._c_steps.inc()
-            self._c_lanes.inc(self.B)
-            self._c_tokens.inc(int(am.sum()))
-            if pm.any():
-                self._c_prefill.inc(int(pm.sum()))
-                for b in np.nonzero(pm & (self._cpos >=
-                                          self._ctxlen))[0]:
-                    # prefix fully consumed this step: the lane's cache now
-                    # equals begin_decode(prefix=ctx) — snapshot it at the
-                    # boundary so later jobs skip this prefill entirely
-                    key = self._cachekey[int(b)]
-                    if key is not None and self.prefix_cache is not None \
-                            and hasattr(self.predictor, "snapshot_slot"):
-                        self.prefix_cache.insert(
-                            key, self.predictor.snapshot_slot(self._state,
-                                                              int(b)))
-                    self._cachekey[int(b)] = None
-            for b in np.nonzero(m & (self._t >= self._valid))[0]:
-                b = int(b)
-                fin = self._tasks[b]
-                with obs.span("service.finish_slot", self.registry,
-                              tags={"job": fin.job.job_id,
-                                    "chunk": fin.chunk_index},
-                              mirror=False) \
-                        if rec is not None else obs.trace.NULL:
-                    self._finish_slot(b)
-        if tel and self.log_every \
-                and self._c_steps.value % self.log_every == 0:
-            obs.log("scheduler.progress", steps=self._c_steps.value,
-                    occupancy=round(self.stats.occupancy, 4),
-                    chunks=self._c_chunks.value,
-                    queued=len(self._queue),
-                    failures=self._c_failures.value)
-        return True
+        # write decoded tokens; advance every coding lane. Prefill
+        # lanes feed their next context token instead — their logits
+        # this step are discarded (context conditioning only).
+        nxt = np.where(dm, syms, truth).astype(np.int32)
+        for b in np.nonzero(pm)[0]:
+            nxt[b] = self._ctx[b][self._cpos[b]]
+        self._tok_buf[dm, self._t[dm]] = nxt[dm]
+        self._prev = np.where(m, nxt, self._prev).astype(np.int32)
+        self._t[am] += 1
+        self._cpos[pm] += 1
+        self._c_steps.inc()
+        self._c_lanes.inc(self.B)
+        self._c_tokens.inc(int(am.sum()))
+        if pm.any():
+            self._c_prefill.inc(int(pm.sum()))
+            for b in np.nonzero(pm & (self._cpos >= self._ctxlen))[0]:
+                # prefix fully consumed this step: the lane's cache now
+                # equals begin_decode(prefix=ctx) — snapshot it at the
+                # boundary so later jobs skip this prefill entirely
+                key = self._cachekey[int(b)]
+                if key is not None and self.prefix_cache is not None \
+                        and hasattr(self.predictor, "snapshot_slot"):
+                    self.prefix_cache.insert(
+                        key, self.predictor.snapshot_slot(self._state,
+                                                          int(b)))
+                self._cachekey[int(b)] = None
+        for b in np.nonzero(m & (self._t >= self._valid))[0]:
+            b = int(b)
+            fin = self._tasks[b]
+            with obs.span("service.finish_slot", self.registry,
+                          tags={"job": fin.job.job_id,
+                                "chunk": fin.chunk_index}):
+                self._finish_slot(b)
 
     def _finish_slot(self, b: int) -> None:
         task = self._tasks[b]

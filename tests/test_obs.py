@@ -314,3 +314,149 @@ def test_lint_tool_flags_calls_not_strings(tmp_path):
 def test_repo_tree_passes_lint():
     lint = _load_lint()
     assert lint.lint(REPO / "src" / "repro") == []
+
+
+# ------------------------------------------- per-step spans on the served path
+STEP_SPANS = ("service.step", "service.step/model.decode_step",
+              "service.step/model.decode_step/transfer.logits_to_host",
+              "service.step/cdf.build", "service.step/coder.step")
+
+
+def _tiny_model_service(topk=8, slots=4, chunk=16):
+    import jax
+
+    from helpers import tiny
+    from repro.models import init_params
+    from repro.serve.engine import ModelPredictor
+    cfg = tiny("dense", vocab_size=258)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    pred = ModelPredictor(params, cfg, bos_id=257)
+    return CompressionService(pred, slots=slots, chunk_size=chunk, topk=topk)
+
+
+def _span_counts(reg):
+    return {k[len("span."):-len(".seconds")]: v["count"]
+            for k, v in reg.snapshot().items() if k.startswith("span.")}
+
+
+def _model_roundtrip(svc, seed=31, n=100):
+    toks = np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
+    blob, _ = svc.submit_compress(toks).result()
+    assert np.array_equal(svc.submit_decompress(blob).result(), toks)
+    return blob
+
+
+def test_service_step_spans_once_per_model_step():
+    """Every model step opens each per-step span exactly once, all in
+    the service's registry (the engine's and the coder's spans inherit
+    it), and model.decode_step never nests in itself."""
+    glob0 = set(_span_counts(obs.registry()))
+    svc = _tiny_model_service()
+    _model_roundtrip(svc)
+    spans = _span_counts(svc.registry)
+    steps = svc.stats.model_steps
+    assert steps > 0
+    for path in STEP_SPANS:
+        assert spans[path] == steps, path
+    assert spans["service.step/service.finish_slot"] \
+        == svc.stats.chunks_completed
+    # one flush per compressed chunk: 100 tokens in chunks of 16
+    assert spans["service.step/service.finish_slot/rans.flush_slot"] \
+        == -(-100 // 16)
+    assert spans["service.step/service.refill"] >= 1
+    assert spans["service.step/service.refill/model.reset_slots"] >= 1
+    for path in spans:
+        assert path.count("model.decode_step") <= 1, path
+        assert path.startswith("service.step"), path
+    assert set(_span_counts(obs.registry())) == glob0
+    assert svc.snapshot()["phases"]["model"] > 0
+
+
+@pytest.mark.parametrize("topk", [8, 0])
+def test_transfer_counters_match_shapes(topk):
+    """transfer.d2h_bytes / h2d_bytes equal the bytes of the arrays each
+    step moves, computed from their shapes and dtypes: logits down and
+    back up, ids and CDFs down, previous tokens and refill masks up."""
+    from repro.core.cdf import full_cdf_jit, topk_cdf_jit
+    svc = _tiny_model_service(topk=topk)
+    _model_roundtrip(svc)
+    B, pred = svc.slots, svc.predictor
+    pred.set_decode_len(svc.chunk_size)
+    logits, _ = pred.decode_step(pred.begin_decode(B),
+                                 np.zeros(B, np.int32))
+    outs = topk_cdf_jit(logits, topk, svc.precision) if topk \
+        else (full_cdf_jit(logits, svc.precision),)
+    fetched = sum(np.asarray(o).nbytes for o in outs)
+    steps = svc.stats.model_steps
+    resets = _span_counts(svc.registry)[
+        "service.step/service.refill/model.reset_slots"]
+    reg = svc.registry
+    assert reg.value("transfer.d2h_bytes") == steps * (logits.nbytes
+                                                       + fetched)
+    assert reg.value("transfer.h2d_bytes") == \
+        steps * (logits.nbytes + B * 4) + resets * B
+
+
+def test_span_registry_inheritance_survives_exceptions():
+    """A span with no registry records into the innermost open span's
+    registry, else the global one — also after an exception unwinds
+    spans, and for timeline-only spans under a disabled registry."""
+    own, glob = MetricsRegistry(), MetricsRegistry()
+    prev = obs.set_registry(glob)
+    try:
+        assert obs.trace.current_registry() is glob
+        with obs.span("outer", own):
+            assert obs.trace.current_registry() is own
+            with pytest.raises(RuntimeError):
+                with obs.span("inner"):
+                    assert obs.trace.current_registry() is own
+                    raise RuntimeError("boom")
+            assert obs.trace.current_registry() is own
+            with obs.span("after"):
+                pass
+        with pytest.raises(RuntimeError):
+            with obs.span("raising", own):
+                raise RuntimeError("boom")
+        assert obs.trace.current_registry() is glob
+        with obs.span("top"):
+            pass
+        off = MetricsRegistry(enabled=False)
+        with obs.TimelineRecorder() as rec:
+            with obs.span("quiet", off):
+                with obs.span("child"):
+                    assert obs.trace.current_registry() is off
+    finally:
+        obs.set_registry(prev)
+    assert set(_span_counts(own)) == {"outer", "outer/inner",
+                                      "outer/after", "raising"}
+    assert set(_span_counts(glob)) == {"top"}
+    assert _span_counts(off) == {}
+    assert [e.path for e in rec.events()] == ["quiet", "quiet/child"]
+
+
+def test_disabled_registry_records_no_spans_and_keeps_bytes():
+    on = _tiny_model_service()
+    off = _tiny_model_service()
+    off.registry.enabled = False
+    assert _model_roundtrip(on) == _model_roundtrip(off)
+    assert _span_counts(off.registry) == {}
+    for name in ("transfer.d2h_bytes", "transfer.h2d_bytes",
+                 "scheduler.step_compiles"):
+        assert off.registry.value(name) == 0
+    assert off.stats.model_steps == on.stats.model_steps
+
+
+def test_step_compiles_zero_when_warm_and_counts_rebuild():
+    """scheduler.step_compiles reads 0 over a warmed run and counts the
+    compiles a forced geometry rebuild (a shared prefix sets a context
+    budget, so a new cache length) brings into a step."""
+    svc = _tiny_model_service()
+    _model_roundtrip(svc)                    # compiles every program
+    c = svc.registry.counter("scheduler.step_compiles")
+    warm = c.value
+    _model_roundtrip(svc, seed=32)
+    assert c.value == warm
+    toks = np.random.default_rng(33).integers(0, 256, 40).astype(np.int32)
+    svc.submit_compress(toks, shared_prefix=np.arange(5)).result()
+    assert svc.scheduler._ctx_budget == 5
+    assert c.value > warm
