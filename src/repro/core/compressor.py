@@ -210,7 +210,9 @@ class PredictorAdapter(Protocol):
         ...
 
     def decode_step(self, state, prev_tokens: np.ndarray):
-        """(state, prev (B,) int32) -> (logits (B, V), new state)."""
+        """(state, prev (B,) int32) -> (logits (B, V), new state). The
+        logits may be a device array (``jax.Array``) or a host one; a
+        caller that reads them on the host converts with ``np.asarray``."""
         ...
 
 
@@ -1344,13 +1346,14 @@ class LLMCompressor:
             # every lane codes position t at step t
             for t in range(C):
                 lg, state = self.predictor.decode_step(state, prev)
-                logits[:, t] = lg
+                logits[:, t] = np.asarray(lg)
                 prev = batch[:, t]
             return logits
         cl = np.asarray(ctx_len, np.int64)
         lanes = np.arange(B)
         for s in range(int(cl.max(initial=0)) + C):
             lg, state = self.predictor.decode_step(state, prev)
+            lg = np.asarray(lg)
             t = s - cl                       # per-lane chunk position
             m = (t >= 0) & (t < C)
             rows = np.nonzero(m)[0]

@@ -6,7 +6,10 @@ implements core.compressor.PredictorAdapter over any model-zoo config:
   * score_chunks — one jitted teacher-forced forward over (B, C) chunks
     (prefill-shaped; on the production mesh this is the pjit `score_step`).
   * decode loop — jitted single-token step. The cache is not donated:
-    each step writes a new cache beside the one it read.
+    each step writes a new cache beside the one it read. The step's
+    logits stay on the device: ``decode_step`` returns the program's
+    ``jax.Array``, which the service's CDF program reads in place, and
+    only callers that need host logits copy them.
 
 The BOS convention: the model input for chunk tokens x_0..x_{C-1} is
 [BOS, x_0, .., x_{C-2}], so logits[t] parameterizes P(x_t | x_<t) with a
@@ -265,18 +268,15 @@ class ModelPredictor:
         self._decode_max_len = int(n)
 
     def decode_step(self, state, prev_tokens: np.ndarray):
-        """One decode step: (B,) previous tokens -> (B, V) logits on the
-        host and the next state. The wait for the program ends before
-        ``transfer.logits_to_host`` opens, so that span times the copy."""
+        """One decode step: (B,) previous tokens -> (B, V) logits and the
+        next state. The logits are the ``jax.Array`` the program writes:
+        nothing waits for it and nothing is copied to the host, so the
+        service hands them straight to its CDF program. A caller that
+        reads them on the host converts them (``np.asarray``)."""
         prev = np.asarray(prev_tokens, np.int32)
         _count_bytes("transfer.h2d_bytes", prev)
-        logits, state = self._decode(self.params, state, jnp.asarray(prev),
-                                     self.extra_batch)
-        logits.block_until_ready()
-        with obs.span("transfer.logits_to_host"):
-            logits = np.asarray(logits)
-        _count_bytes("transfer.d2h_bytes", logits)
-        return logits, state
+        return self._decode(self.params, state, jnp.asarray(prev),
+                            self.extra_batch)
 
     def verify_steps(self, state, seq: np.ndarray):
         """Speculative-decode verify program: score seq (B, T) — column 0

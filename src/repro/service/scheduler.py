@@ -38,12 +38,19 @@ compile counters, periodic progress lines) is gated on
 ``registry.enabled``, and none of it can change output bytes: every
 telemetry read happens *after* the coder ops it describes.
 
+The logits never leave the device: the predictor's ``decode_step``
+returns them as the program's ``jax.Array`` (an adapter may return a
+host array instead), the CDF program is dispatched on them right behind
+the model program, and the host fetches only its ids and CDFs — a few
+KB a step in place of the (B, V) logits down and back up.
+
 Every step opens the same spans, each once: ``service.step`` around it
 all, ``service.refill`` when slots are refilled, ``model.decode_step``
-around the model call up to the logits on the host (the predictor's
-``transfer.logits_to_host`` nests in it), ``cdf.build`` around the CDF
-program and the fetch of its ids and CDFs, ``coder.step`` around the
-host coder, and ``service.finish_slot`` (with ``rans.flush_slot``) per
+around the model call (its dispatch: nothing waits there),
+``cdf.build`` around the CDF program's dispatch, the wait for both
+programs and the fetch, with ``transfer.cdf_to_host`` inside it timing
+the copy of the ids and CDFs alone, ``coder.step`` around the host
+coder, and ``service.finish_slot`` (with ``rans.flush_slot``) per
 finished slot. Spans and counters the predictor and the coder open
 without a registry land in the scheduler's (``obs.trace``).
 """
@@ -51,6 +58,7 @@ from __future__ import annotations
 
 import heapq
 
+import jax
 import numpy as np
 
 from repro import obs
@@ -414,7 +422,6 @@ class SlotScheduler:
         with obs.span("model.decode_step", self.registry):
             logits, self._state = self.predictor.decode_step(
                 self._state, self._prev)
-            logits = np.asarray(logits)
         pm = m & (self._cpos < self._ctxlen)     # prefilling context
         am = m & ~pm                             # coding this step
         dm = am & self._is_dec
@@ -426,11 +433,9 @@ class SlotScheduler:
             # XLA top-k -> quantized CDF on the device: no host pmf
             # cumsum per step; same integers as the host quantizer
             with obs.span("cdf.build", self.registry):
-                ids, cdfs = topk_cdf_jit(logits, self.topk, self.precision)
-                ids, cdfs = np.asarray(ids), np.asarray(cdfs)   # (B, K+2)
-                if tel:
-                    self._c_h2d.inc(logits.nbytes)
-                    self._c_d2h.inc(ids.nbytes + cdfs.nbytes)
+                ids, cdfs = self._fetch(                        # (B, K+2)
+                    topk_cdf_jit(logits, self.topk, self.precision),
+                    logits, tel)
                 cdfs = cdfs.astype(np.int64)
             with obs.span("coder.step", self.registry):
                 if dm.any():
@@ -464,10 +469,8 @@ class SlotScheduler:
                             self._nesc[em] += 1
         else:
             with obs.span("cdf.build", self.registry):
-                cdfs = np.asarray(full_cdf_jit(logits, self.precision))
-                if tel:                                         # (B, V+1)
-                    self._c_h2d.inc(logits.nbytes)
-                    self._c_d2h.inc(cdfs.nbytes)
+                cdfs, = self._fetch(                            # (B, V+1)
+                    (full_cdf_jit(logits, self.precision),), logits, tel)
                 cdfs = cdfs.astype(np.int64)
             with obs.span("coder.step", self.registry):
                 if dm.any():
@@ -510,6 +513,21 @@ class SlotScheduler:
                           tags={"job": fin.job.job_id,
                                 "chunk": fin.chunk_index}):
                 self._finish_slot(b)
+
+    def _fetch(self, outs, logits, tel: bool):
+        """Wait for the CDF program's outputs ``outs`` (and the model
+        program ahead of it), then copy them to the host in one
+        ``device_get`` inside ``transfer.cdf_to_host``, so that span
+        times the copy alone. Host logits, from an adapter that returns
+        them, were uploaded into the CDF program and count as sent."""
+        jax.block_until_ready(outs)
+        with obs.span("transfer.cdf_to_host", self.registry):
+            outs = jax.device_get(outs)
+        if tel:
+            self._c_d2h.inc(sum(o.nbytes for o in outs))
+            if not isinstance(logits, jax.Array):
+                self._c_h2d.inc(logits.nbytes)
+        return outs
 
     def _finish_slot(self, b: int) -> None:
         task = self._tasks[b]

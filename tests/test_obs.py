@@ -318,20 +318,25 @@ def test_repo_tree_passes_lint():
 
 # ------------------------------------------- per-step spans on the served path
 STEP_SPANS = ("service.step", "service.step/model.decode_step",
-              "service.step/model.decode_step/transfer.logits_to_host",
-              "service.step/cdf.build", "service.step/coder.step")
+              "service.step/cdf.build",
+              "service.step/cdf.build/transfer.cdf_to_host",
+              "service.step/coder.step")
 
 
-def _tiny_model_service(topk=8, slots=4, chunk=16):
+def _tiny_predictor(vocab=258, cls=None):
     import jax
 
     from helpers import tiny
     from repro.models import init_params
     from repro.serve.engine import ModelPredictor
-    cfg = tiny("dense", vocab_size=258)
+    cfg = tiny("dense", vocab_size=vocab)
     params = init_params(cfg, jax.random.PRNGKey(0))
-    pred = ModelPredictor(params, cfg, bos_id=257)
-    return CompressionService(pred, slots=slots, chunk_size=chunk, topk=topk)
+    return (cls or ModelPredictor)(params, cfg, bos_id=257)
+
+
+def _tiny_model_service(topk=8, slots=4, chunk=16, vocab=258, cls=None):
+    return CompressionService(_tiny_predictor(vocab, cls), slots=slots,
+                              chunk_size=chunk, topk=topk)
 
 
 def _span_counts(reg):
@@ -375,8 +380,9 @@ def test_service_step_spans_once_per_model_step():
 @pytest.mark.parametrize("topk", [8, 0])
 def test_transfer_counters_match_shapes(topk):
     """transfer.d2h_bytes / h2d_bytes equal the bytes of the arrays each
-    step moves, computed from their shapes and dtypes: logits down and
-    back up, ids and CDFs down, previous tokens and refill masks up."""
+    step moves, computed from their shapes and dtypes: ids and CDFs
+    down, previous tokens and refill masks up. The logits stay on the
+    device and count in neither."""
     from repro.core.cdf import full_cdf_jit, topk_cdf_jit
     svc = _tiny_model_service(topk=topk)
     _model_roundtrip(svc)
@@ -391,10 +397,62 @@ def test_transfer_counters_match_shapes(topk):
     resets = _span_counts(svc.registry)[
         "service.step/service.refill/model.reset_slots"]
     reg = svc.registry
-    assert reg.value("transfer.d2h_bytes") == steps * (logits.nbytes
-                                                       + fetched)
-    assert reg.value("transfer.h2d_bytes") == \
-        steps * (logits.nbytes + B * 4) + resets * B
+    assert reg.value("transfer.d2h_bytes") == steps * fetched
+    assert reg.value("transfer.h2d_bytes") == steps * B * 4 + resets * B
+
+
+def test_host_logits_count_as_uploaded():
+    """An adapter that returns host logits (here a numpy table) has them
+    uploaded into the CDF program each step: h2d counts their bytes."""
+    svc = CompressionService(GoldenPredictor(), slots=4, chunk_size=16,
+                             topk=8)
+    toks = np.random.default_rng(5).integers(0, 64, 60).astype(np.int32)
+    svc.submit_compress(toks).result()
+    B, pred = svc.slots, svc.predictor
+    logits, _ = pred.decode_step(pred.begin_decode(B), np.zeros(B, np.int32))
+    assert isinstance(logits, np.ndarray)
+    assert svc.registry.value("transfer.h2d_bytes") == \
+        svc.stats.model_steps * logits.nbytes
+    assert svc.registry.value("transfer.d2h_bytes") == \
+        svc.stats.model_steps * B * (8 + 8 + 2) * 4
+
+
+@pytest.mark.parametrize("topk", [8, 0])
+def test_device_logits_containers_byte_identical(topk):
+    """The service over device logits writes the same bytes as over an
+    adapter that copies them to the host first (the CDF program then
+    uploads them again): the CDF program reads the same bfloat16 bits
+    either way. Each container decodes back to its tokens."""
+    import jax
+
+    from repro.serve.engine import ModelPredictor
+
+    class HostLogits(ModelPredictor):
+        def decode_step(self, state, prev_tokens):
+            logits, state = super().decode_step(state, prev_tokens)
+            return np.asarray(logits), state
+
+    dev = _tiny_model_service(topk=topk)
+    host = _tiny_model_service(topk=topk, cls=HostLogits)
+    pred = dev.predictor
+    pred.set_decode_len(dev.chunk_size)
+    logits, _ = pred.decode_step(pred.begin_decode(dev.slots),
+                                 np.zeros(dev.slots, np.int32))
+    assert isinstance(logits, jax.Array)
+    for seed, n in ((41, 100), (42, 37)):
+        assert _model_roundtrip(dev, seed, n) == \
+            _model_roundtrip(host, seed, n)
+
+
+@pytest.mark.parametrize("vocab", [258, 1030])
+def test_logits_never_reach_the_host(vocab):
+    """At top-K the host fetches (B, K) ids and (B, K+2) CDFs a step,
+    int32, whatever the vocabulary: the (B, V) logits are never copied."""
+    K = 8
+    svc = _tiny_model_service(topk=K, vocab=vocab)
+    _model_roundtrip(svc)
+    assert svc.registry.value("transfer.d2h_bytes") == \
+        svc.stats.model_steps * svc.slots * (K + K + 2) * 4
 
 
 def test_span_registry_inheritance_survives_exceptions():
