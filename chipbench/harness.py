@@ -4,17 +4,21 @@ A cell names a configuration and a traffic mix; everything about them
 is read from files found by those names:
 
     chipbench/configs/<config>.json     sizes, service, pool, weight init
+    chipbench/reference/<name>.py       the plain reference the config
+                                        names (``"reference"``, default
+                                        ``dense``)
     chipbench/traffic/<mix>.json        the generator's parameters
     chipbench/limits/<cell>.json        the limits ``correct`` is held to
     chipbench/metrics/<metric>.py       one reader per metric
 
-A run builds the configuration's weights (from its fixed ``init.seed``)
-on the device, has the model write a pool of documents from BOS under
-the run's seed, builds ``CompressionService`` at the
-configuration's slots, chunk and top-K, warms the cell's shapes, then
-drives ``submit_compress`` / ``submit_decompress`` and ``poll()`` for the
-window as a user does (``drive``), and checks what came back against the
-plain reference (``run``).
+A run builds the configuration's weights (the reference's
+``make_weights``, from the file's fixed ``init.seed``) on the device,
+has the model write a pool of documents from BOS under the run's seed,
+builds ``CompressionService`` at the configuration's slots, chunk and
+top-K, warms the cell's shapes, then drives ``submit_compress`` /
+``submit_decompress`` and ``poll()`` for the window as a user does
+(``drive``), and checks what came back against the plain reference
+(``run``).
 """
 from __future__ import annotations
 
@@ -34,16 +38,6 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 BENCH = ROOT / "chipbench"
 if str(ROOT / "src") not in sys.path:
     sys.path.insert(0, str(ROOT / "src"))
-
-# program fields set from a configuration file's ``model`` keys
-_PROGRAM_FIELDS = {
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "head_dim": "d_head", "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
-    "qk_norm": "qk_norm", "torch_dtype": "dtype",
-}
 
 
 def log(msg: str) -> None:
@@ -171,11 +165,30 @@ class CompileClock:
 
 
 # ------------------------------------------------------------ the program
-def program_config(conf: dict):
+def reference_module(conf: dict, bench_dir: pathlib.Path = BENCH):
+    """The plain reference a configuration file names under
+    ``"reference"`` (``dense`` where it names none): the module
+    ``<bench_dir>/reference/<name>.py``, imported once as
+    ``chipbench.reference.<name>``, the name the readers find it by."""
+    name = conf.get("reference", "dense")
+    full = f"chipbench.reference.{name}"
+    if full not in sys.modules and bench_dir != BENCH:
+        spec = importlib.util.spec_from_file_location(
+            full, bench_dir / "reference" / f"{name}.py")
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[full] = mod
+    return importlib.import_module(full)
+
+
+def program_config(conf: dict, bench_dir: pathlib.Path = BENCH):
     """The program's ModelConfig for a configuration file: its module's
-    CONFIG with every ``model`` number the file holds set on it."""
+    CONFIG with every ``model`` number the file holds set on it, by the
+    ``PROGRAM_FIELDS`` of the reference it names; ``program.fields``
+    wins."""
     mod = importlib.import_module(conf["program"]["module"])
-    kw = {f: conf["model"][k] for k, f in _PROGRAM_FIELDS.items()
+    fields = reference_module(conf, bench_dir).PROGRAM_FIELDS
+    kw = {f: conf["model"][k] for k, f in fields.items()
           if k in conf["model"]}
     kw.update(conf["program"].get("fields", {}))
     cfg = mod.CONFIG.with_(**kw)
@@ -193,7 +206,8 @@ def model_spec(conf: dict, cfg) -> dict:
 
 
 def check_layout(params, cfg) -> None:
-    """The tree handed to the service has the program's schema shapes."""
+    """The tree handed to the service (the reference's ``make_weights``)
+    has the program's schema shapes."""
     import jax
     from repro.models.schema import abstract_params
     want = jax.tree_util.tree_map(lambda a: a.shape, abstract_params(cfg))
@@ -219,7 +233,6 @@ class Bench:
     def __init__(self, cell: dict, seed: int, clock: CompileClock,
                  t_start: float):
         import jax
-        from chipbench.reference import dense
         from repro.serve.engine import ModelPredictor
         from repro.service import CompressionService
 
@@ -227,22 +240,23 @@ class Bench:
         self.sub = seeds(seed)
         conf, mix = cell["config"], cell["traffic"]
         self.conf, self.mix = conf, mix
-        self.cfg = program_config(conf)
+        self.ref = ref = reference_module(conf, cell["bench_dir"])
+        self.cfg = program_config(conf, cell["bench_dir"])
         self.m = model_spec(conf, self.cfg)
         svc, pool = conf["service"], conf["pool"]
         self.split = {"tpu_init_s": time.perf_counter() - t_start}
         compile0, hits0 = clock.seconds, clock.cache_hits
 
         t = time.perf_counter()
-        params = dense.make_weights(self.m, conf["init"],
-                                    conf["init"]["seed"])
+        params = ref.make_weights(self.m, conf["init"],
+                                  conf["init"]["seed"])
         jax.block_until_ready(params)
         check_layout(params, self.cfg)
         self.split["weights_s"] = time.perf_counter() - t
 
         t = time.perf_counter()
         self.bos = self.cfg.vocab_size - 1
-        self.pool = dense.sample_documents(
+        self.pool = ref.sample_documents(
             self.m, params, n_docs=pool["documents"], batch=pool["batch"],
             n_tokens=svc["chunk_size"], top_k=pool["sample_top_k"],
             bos=self.bos, seed=self.sub["pool"])
@@ -297,6 +311,30 @@ class Bench:
         gc.collect()
 
 
+def registry_values(reg) -> dict:
+    """Counters and span-histogram sums/counts of a service registry, by
+    name (spans by path)."""
+    out = {"counters": {}, "spans": {}}
+    for name, m in reg.snapshot().items():
+        if m["type"] == "counter":
+            out["counters"][name] = m["value"]
+        elif name.startswith("span.") and name.endswith(".seconds"):
+            out["spans"][name[5:-8]] = {"seconds": m["sum"],
+                                        "count": m["count"]}
+    return out
+
+
+def registry_delta(after: dict, before: dict) -> dict:
+    zero = {"seconds": 0.0, "count": 0}
+    return {
+        "counters": {k: v - before["counters"].get(k, 0)
+                     for k, v in after["counters"].items()},
+        "spans": {k: {f: v[f] - before["spans"].get(k, zero)[f]
+                      for f in zero}
+                  for k, v in after["spans"].items()},
+    }
+
+
 # --------------------------------------------------------------- the window
 class _Tracer:
     """Profiles the stretch [start, end) of the window (seconds from its
@@ -340,8 +378,10 @@ def drive(b: Bench, seconds: float, *, trace_dir: str | None = None,
     window's close: what it finished in the window is its work. An open
     loop submits each job when it is due, and after the close drains the
     jobs due in the window up to ``drain_cap_s``. The scheduler's
-    counters and the seconds spent inside ``poll()`` are read at the
-    window's close."""
+    counters, the service registry's counters and span sums
+    (``registry``) and the seconds spent inside ``poll()`` are read at
+    the window's close. Each finished job records its container's
+    ``bytes``."""
     import jax
     from chipbench import traffic
     mix, svc = b.mix, b.svc
@@ -363,14 +403,16 @@ def drive(b: Bench, seconds: float, *, trace_dir: str | None = None,
         nxt = 0
         cap = seconds + mix["drain_cap_s"]
     window = None
-    c0 = b.counters()
+    c0, r0 = b.counters(), registry_values(svc.registry)
     t0 = time.perf_counter()
     while True:
         now = time.perf_counter() - t0
         tracer.tick(now)
         if window is None and now >= seconds:
             window = {"t_close": now, "poll_s": poll_s,
-                      "counters": _delta(b.counters(), c0)}
+                      "counters": _delta(b.counters(), c0),
+                      "registry": registry_delta(
+                          registry_values(svc.registry), r0)}
             if closed:
                 break
         if closed:
@@ -427,8 +469,10 @@ def drive(b: Bench, seconds: float, *, trace_dir: str | None = None,
             res = h.result()
             if closed:
                 j["blob"] = res[0]
+                j["bytes"] = len(res[0])
             else:
                 j["tokens_out"] = res
+                j["bytes"] = len(b.blobs[j["replay"]])
             j["chunks"] = {d.chunk_index: d.coded_bits
                            for d in h.diagnostics.chunks}
         except Exception as e:                        # noqa: BLE001
@@ -536,22 +580,45 @@ def chunk_table(sample: list, tokens_of, C: int):
             np.asarray(prog, np.float64))
 
 
-def reference_bits(conf: dict, m: dict, chunks, valid, block: int,
+def reference_bits(ref, conf: dict, m: dict, chunks, valid, block: int,
                    int8: bool = False) -> np.ndarray:
-    """Reference code length of each chunk. The reference makes its own
-    weights from the configuration's seed; with ``int8`` it is the
-    control."""
+    """Code length of each chunk under the reference module ``ref``, which
+    makes its own weights from the configuration's seed; with ``int8`` it
+    is the control."""
     import jax
-    from chipbench.reference import codelen, dense
+    from chipbench.reference import codelen
     svc = conf["service"]
-    params = dense.make_weights(m, conf["init"], conf["init"]["seed"])
-    ref = codelen.chunk_bits(m, params, chunks, valid, k=svc["topk"],
-                             precision=svc["precision"],
-                             bos=m["vocab_size"] - 1, block=block,
-                             int8=int8)
+    params = ref.make_weights(m, conf["init"], conf["init"]["seed"])
+    bits = codelen.chunk_bits(ref, m, params, chunks, valid, k=svc["topk"],
+                              precision=svc["precision"],
+                              bos=m["vocab_size"] - 1, block=block,
+                              int8=int8)
     del params
     jax.clear_caches()
-    return ref
+    return bits
+
+
+def read_trace(tdir: str, keep: str | None = None):
+    """The device's and the program spans' reductions of the profiler
+    trace written under ``tdir`` (``chipbench/trace.py``,
+    ``chipbench/spans.py``), or (None, None) where none was written;
+    ``tdir`` is removed, the trace copied to ``keep`` first."""
+    from chipbench import spans, trace as tr
+    files = sorted(pathlib.Path(tdir).rglob("*.xplane.pb"))
+    red = sp = None
+    if files:
+        if keep:
+            pathlib.Path(keep).mkdir(parents=True, exist_ok=True)
+            shutil.copy(files[-1], keep)
+        red, sp = tr.reduce_file(files[-1]), spans.reduce_file(files[-1])
+    shutil.rmtree(tdir, ignore_errors=True)
+    return red, sp
+
+
+def log_idle_split(sp: dict) -> None:
+    from chipbench import spans
+    log(f"idle by program span ({sp['steps']} steps, {sp['idle_s']!r} s "
+        f"idle of {sp['window_s']!r} s):\n" + spans.table(sp))
 
 
 def gaps(bits, ref, valid) -> dict:
@@ -617,8 +684,9 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     rt_s = time.perf_counter() - t
     rec.update(setup_s=setup_s, mean_pos=mean_position(rec, C),
                model=b.m, service=b.conf["service"],
+               reference=b.ref.__name__.rsplit(".", 1)[1],
                peaks=peaks_for(dev.device_kind) if require_tpu else None)
-    m, split = b.m, dict(b.split)
+    m, split, ref_mod = b.m, dict(b.split), b.ref
     replay = getattr(b, "replay", None)
     b.free()
     del b
@@ -626,27 +694,18 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         sample, lambda j: j["tokens"] if "tokens" in j
         else replay[j["replay"]], C)
     t = time.perf_counter()
-    ref = reference_bits(cell["config"], m, chunks, valid,
+    ref = reference_bits(ref_mod, cell["config"], m, chunks, valid,
                          limits["reference_block"])
     ref_s = time.perf_counter() - t
     got = gaps(prog, ref, valid)
     ctl = None
     if control:
-        ctl = gaps(reference_bits(cell["config"], m, chunks, valid,
+        ctl = gaps(reference_bits(ref_mod, cell["config"], m, chunks, valid,
                                   limits["reference_block"], int8=True),
                    ref, valid)
 
-    red = None
-    if trace:
-        from chipbench import trace as tr
-        files = sorted(pathlib.Path(tdir).rglob("*.xplane.pb"))
-        if files:
-            if keep_trace:
-                pathlib.Path(keep_trace).mkdir(parents=True, exist_ok=True)
-                shutil.copy(files[-1], keep_trace)
-            red = tr.reduce_file(files[-1])
-        shutil.rmtree(tdir, ignore_errors=True)
-    rec["trace"] = red
+    red, sp = read_trace(tdir, keep_trace) if trace else (None, None)
+    rec["trace"], rec["spans"] = red, sp
 
     metrics = {}
     for spec in (cell["per_layer"] if trace else cell["end_to_end"]):
@@ -675,6 +734,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         f" max={float(late.max())!r} counters={rec['counters']}")
     if rec["backlog"]:
         log(f"backlog (s, jobs outstanding): {rec['backlog']}")
+    if sp:
+        log_idle_split(sp)
     log(f"memory_peak_bytes={peak} compile_cache={cache_dir}")
     log(f"check: sampled_jobs={len(sample)} sampled_chunks={len(valid)} "
         f"sampled_tokens={int(valid.sum())} roundtrip_s={rt_s!r} "

@@ -5,14 +5,14 @@ the service's registry and its program spans read.
         [--trace 0|1] [--keep-trace DIR]
 
 Each run builds and drives the cell as ``chipbench/run.py`` does, with
-the stretch of the window traced unless ``--trace 0``, and adds to the
-run's record
+the stretch of the window traced unless ``--trace 0``; its record holds
 ``registry`` (the window's deltas of the service registry's counters and
 ``span.<path>.seconds`` sums and counts) and ``spans`` (the device's
-idle time split by program span, ``chipbench/spans.py``). It skips the
-check against the reference: ``run.py`` decides ``correct``. Each run
-prints the per-span idle table on standard error and one JSON line on
-standard output: the readers' values under ``metrics``.
+idle time split by program span, ``chipbench/spans.py``), as a run of
+``run.py`` does. It skips the check against the reference: ``run.py``
+decides ``correct``. Each run prints the per-span idle table on standard
+error and one JSON line on standard output: the readers' values under
+``metrics``.
 """
 import time
 
@@ -21,7 +21,6 @@ T_START = time.perf_counter()
 import argparse  # noqa: E402
 import json  # noqa: E402
 import pathlib  # noqa: E402
-import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 
@@ -33,59 +32,23 @@ READERS = ("tokens_per_s", "transfer_mb_per_step", "coder_host_ms", "scheduler_h
            "decode_device_ms", "cdf_device_ms")
 
 
-def registry_values(reg) -> dict:
-    """Counters and span-histogram sums/counts of a registry, by name
-    (spans by path)."""
-    out = {"counters": {}, "spans": {}}
-    for name, m in reg.snapshot().items():
-        if m["type"] == "counter":
-            out["counters"][name] = m["value"]
-        elif name.startswith("span.") and name.endswith(".seconds"):
-            out["spans"][name[5:-8]] = {"seconds": m["sum"],
-                                        "count": m["count"]}
-    return out
-
-
-def registry_delta(after: dict, before: dict) -> dict:
-    zero = {"seconds": 0.0, "count": 0}
-    return {
-        "counters": {k: v - before["counters"].get(k, 0)
-                     for k, v in after["counters"].items()},
-        "spans": {k: {f: v[f] - before["spans"].get(k, zero)[f]
-                      for f in zero}
-                  for k, v in after["spans"].items()},
-    }
-
-
 def run(cell_name: str, seed: int, seconds: float, t_start: float,
         trace_on: bool = True, keep_trace=None, require_tpu: bool = True,
         cell=None) -> dict:
-    from chipbench import harness, spans, trace
+    from chipbench import harness
     cell = cell or harness.load_cell(cell_name)
     dev = harness.accelerator(cell["cell"]["chips"], require_tpu)[0]
     harness.compile_cache()
     b = harness.Bench(cell, seed, harness.CompileClock.get(), t_start)
     tdir = tempfile.mkdtemp(prefix="chipbench_layers_") if trace_on else None
-    before = registry_values(b.svc.registry)
     rec = harness.drive(b, seconds, trace_dir=tdir)
-    rec["registry"] = registry_delta(registry_values(b.svc.registry),
-                                     before)
     b.free()
-    files = sorted(pathlib.Path(tdir).rglob("*.xplane.pb")) if tdir else []
-    rec["trace"] = rec["spans"] = None
-    if files:
-        if keep_trace:
-            pathlib.Path(keep_trace).mkdir(parents=True, exist_ok=True)
-            shutil.copy(files[-1], keep_trace)
-        rec["trace"] = trace.reduce_file(files[-1])
-        rec["spans"] = spans.reduce_file(files[-1])
-        harness.log(f"idle by program span ({rec['spans']['steps']} steps, "
-                    f"{rec['spans']['idle_s']!r} s idle of "
-                    f"{rec['spans']['window_s']!r} s):\n"
-                    + spans.table(rec["spans"]))
+    rec["trace"], rec["spans"] = (harness.read_trace(tdir, keep_trace)
+                                  if tdir else (None, None))
+    if rec["spans"]:
+        harness.log_idle_split(rec["spans"])
+    if rec["trace"]:
         harness.log(f"longest idle gaps: {rec['trace']['idle_gaps']}")
-    if tdir:
-        shutil.rmtree(tdir, ignore_errors=True)
     metrics = {}
     for name in READERS:
         v = harness.metric_reader(name, cell["bench_dir"])(rec)

@@ -1,9 +1,10 @@
-"""Container bytes x 8 over input tokens, over every compress job the run
-finished (client side: the bytes the user stores)."""
+"""Container bytes x 8 over input tokens, over every job the run finished
+(client side: the bytes the user stores; for a read-back, the bytes of
+the containers it read)."""
 
 
 def read(rec):
-    done = [j for j in rec["jobs"] if "blob" in j]
+    done = [j for j in rec["jobs"] if "bytes" in j]
     if not done:
         return None
-    return 8.0 * sum(len(j["blob"]) for j in done) / sum(j["n"] for j in done)
+    return 8.0 * sum(j["bytes"] for j in done) / sum(j["n"] for j in done)

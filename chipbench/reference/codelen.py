@@ -11,8 +11,10 @@ escape = 1 - sum of the top K) are quantized to integers summing to
 
 so every slot keeps at least one quantum. A token in slot i costs
 precision - log2(freq_i) bits; an escaped token costs the escape slot's
-bits plus ceil(log2 V) bits for its id. Nothing here imports the system
-under test.
+bits plus ceil(log2 V) bits for its id. The logits are those of the
+reference module ``ref`` the configuration names (``forward`` with its
+``mm``, or ``mm_int8`` for the control). Nothing here imports the
+system under test.
 """
 from __future__ import annotations
 
@@ -21,8 +23,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from . import dense
 
 
 def escape_bits(vocab: int) -> int:
@@ -50,22 +50,22 @@ def token_bits(logits, tokens, k: int, precision: int):
             + jnp.where(slot == k, escape_bits(V), 0)).astype(jnp.float32)
 
 
-@partial(jax.jit, static_argnums=(0, 3, 4, 5, 6))
-def _block_bits(mkey, params, chunks, k, precision, bos, int8):
+@partial(jax.jit, static_argnums=(0, 1, 4, 5, 6, 7))
+def _block_bits(ref, mkey, params, chunks, k, precision, bos, int8):
     """chunks (b, C) tokens -> bits (b, C): position t is coded given
     [BOS, chunk[:t]], as the service codes a chunk from a fresh context."""
     m = dict(mkey)
     inp = jnp.concatenate([jnp.full((chunks.shape[0], 1), bos, chunks.dtype),
                            chunks[:, :-1]], axis=1)
     with jax.default_matmul_precision("highest"):
-        logits = dense.forward(m, params, inp,
-                               dense.mm_int8 if int8 else dense._mm)
+        logits = ref.forward(m, params, inp,
+                             ref.mm_int8 if int8 else ref.mm)
     return token_bits(logits, chunks, k, precision)
 
 
-def chunk_bits(m: dict, params, chunks: np.ndarray, valid: np.ndarray, *,
-               k: int, precision: int, bos: int, block: int,
-               int8: bool = False) -> np.ndarray:
+def chunk_bits(ref, m: dict, params, chunks: np.ndarray,
+               valid: np.ndarray, *, k: int, precision: int, bos: int,
+               block: int, int8: bool = False) -> np.ndarray:
     """Reference code length in bits of each chunk's first ``valid``
     tokens, ``block`` chunks per call (float32, highest precision; with
     ``int8`` every weight product in int8, the control)."""
@@ -76,7 +76,7 @@ def chunk_bits(m: dict, params, chunks: np.ndarray, valid: np.ndarray, *,
     x = np.concatenate([chunks, np.zeros((pad, C), chunks.dtype)])
     out = []
     for i in range(0, len(x), block):
-        out.append(np.asarray(_block_bits(mkey, params,
+        out.append(np.asarray(_block_bits(ref, mkey, params,
                                           jnp.asarray(x[i:i + block]),
                                           k, precision, bos, int8)))
     bits = np.concatenate(out)[:n].astype(np.float64)

@@ -21,6 +21,12 @@ float32 pass over a model larger than half the chip still fits. Under
 layout of the program's model schema (stacked layers, padded vocabulary
 rows), from the seed in one jitted call on the device. The reference
 regenerates the same tree itself when it runs.
+
+This is the reference a configuration file gets when it names none
+(``"reference": "dense"``). A reference module provides what the harness
+and the readers take from it: ``PROGRAM_FIELDS``, ``make_weights``,
+``sample_documents``, ``forward`` with its ``mm`` and ``mm_int8``, and
+the work counts ``flops_per_token`` and ``decode_step_bytes``.
 """
 from __future__ import annotations
 
@@ -31,6 +37,18 @@ import jax
 import jax.numpy as jnp
 
 NEG = -1e30
+BYTES = 2           # weights and key/value cache are served in bfloat16
+
+# program fields (``ModelConfig``) set from a configuration file's
+# ``model`` keys; the file's ``program.fields`` override them
+PROGRAM_FIELDS = {
+    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
+    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+    "head_dim": "d_head", "intermediate_size": "d_ff",
+    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
+    "rms_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
+    "qk_norm": "qk_norm", "torch_dtype": "dtype",
+}
 
 
 # ------------------------------------------------------------------ shapes
@@ -121,7 +139,7 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _mm(x, w):
+def mm(x, w):
     """x . w with float32 accumulation; x is cast to w's type, so bfloat16
     weights give the served one-pass product and float32 weights (under
     "highest") the reference's."""
@@ -138,11 +156,11 @@ def _quant(x, axis):
 
 def mm_int8(x, w):
     """x . w computed in int8: x per row, w per output column (W8A8)."""
-    return _mm(_quant(x.astype(jnp.float32), -1),
+    return mm(_quant(x.astype(jnp.float32), -1),
                _quant(w.astype(jnp.float32), -2))
 
 
-def _qkv(m, lp, x, pos, mm=_mm):
+def _qkv(m, lp, x, pos, mm=mm):
     B, S, _ = x.shape
     H, K, hd = (m["num_attention_heads"], m["num_key_value_heads"],
                 m["head_dim"])
@@ -167,7 +185,7 @@ def _attend(m, q, k, v, mask):
     return o.reshape(q.shape[0], q.shape[1], -1)
 
 
-def _mlp(m, lp, h, mm=_mm):
+def _mlp(m, lp, h, mm=mm):
     x = _norm(h, lp["ln2"], m["rms_norm_eps"])
     return h + mm(jax.nn.silu(mm(x, lp["wi_gate"])) * mm(x, lp["wi_up"]),
                   lp["wo_mlp"])
@@ -188,7 +206,7 @@ def _embed(m, params, tokens):
     return jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
 
 
-def forward(m: dict, params, tokens, mm=_mm):
+def forward(m: dict, params, tokens, mm=mm):
     """Teacher-forced logits (B, S, V) float32 of ``tokens`` (B, S), the
     whole sequence at once with a causal mask. ``mm`` computes every
     product with a weight (``mm_int8`` for the int8 control)."""
@@ -235,7 +253,7 @@ def _step(m: dict, params, cache, tok, t):
         p = jax.nn.softmax(jnp.where(live, s, NEG), axis=-1)
         o = jnp.einsum("bkgt,btkd->bkgd", p.astype(vc.dtype), vc[i],
                        preferred_element_type=jnp.float32)
-        h = h + _mm(o.reshape(B, 1, H * hd), lp["wo"])
+        h = h + mm(o.reshape(B, 1, H * hd), lp["wo"])
         return (_mlp(m, lp, h), kc, vc), None
 
     L = m["num_hidden_layers"]
@@ -243,7 +261,7 @@ def _step(m: dict, params, cache, tok, t):
         layer, (_embed(m, params, tok[:, None]), cache["k"], cache["v"]),
         (params["layers"], jnp.arange(L)))
     h = _norm(h, params["final_norm"], m["rms_norm_eps"])
-    logits = _mm(h, _head(m, params, jnp.bfloat16))[:, 0, :m["vocab_size"]]
+    logits = mm(h, _head(m, params, jnp.bfloat16))[:, 0, :m["vocab_size"]]
     return logits, {"k": k, "v": v}
 
 
@@ -284,3 +302,44 @@ def sample_documents(m: dict, params, *, n_docs: int, batch: int,
         out.append(np.asarray(_sample(mkey, params, batch, n_tokens, top_k,
                                       bos, jax.random.fold_in(key, i))))
     return np.concatenate(out)[:n_docs]
+
+
+# -------------------------------------------------------------- work counts
+# Operations and bytes of the decoder's work, from its shapes, for the
+# readers' roofline and utilization (``chipbench/flops.py``). Positions
+# count from 0, so a token at position ``pos`` attends to ``pos + 1`` keys.
+def layer_params(m: dict) -> int:
+    """Matrix parameters of all layers (norm weights are negligible)."""
+    D, F = m["hidden_size"], m["intermediate_size"]
+    H, K, hd = (m["num_attention_heads"], m["num_key_value_heads"],
+                m["head_dim"])
+    return m["num_hidden_layers"] * (D * hd * (2 * H + 2 * K) + 3 * D * F)
+
+
+def head_params(m: dict) -> int:
+    return m["hidden_size"] * m["vocab_size"]
+
+
+def kv_bytes_per_position(m: dict) -> int:
+    """Key and value bytes one lane stores per position, all layers."""
+    return (2 * m["num_hidden_layers"] * m["num_key_value_heads"]
+            * m["head_dim"] * BYTES)
+
+
+def flops_per_token(m: dict, pos: float) -> float:
+    """Model FLOPs to score one token at position ``pos``: the matrix
+    products, the head, and attention's two products over pos + 1 keys."""
+    attn = (4 * m["num_hidden_layers"] * m["num_attention_heads"]
+            * m["head_dim"] * (pos + 1))
+    return 2.0 * (layer_params(m) + head_params(m)) + attn
+
+
+def decode_step_bytes(m: dict, lanes: float, pos: float) -> float:
+    """Least bytes one decode step over ``lanes`` coding lanes at mean
+    position ``pos`` must move: every weight once (the input embedding
+    only its lanes' rows), the cache read up to each lane's position and
+    written at it, and the lanes' logits."""
+    D, V = m["hidden_size"], m["vocab_size"]
+    weights = (layer_params(m) + head_params(m) + lanes * D) * BYTES
+    kv = lanes * (pos + 2) * kv_bytes_per_position(m)
+    return weights + kv + lanes * V * BYTES

@@ -4,9 +4,9 @@ spans: where the device's idle time went on the host.
 The program mirrors its ``obs`` spans into the profiler, so the thread
 that drives the service (the one that holds ``bench.traced``) carries
 one host event per span: ``service.step``, ``service.refill``,
-``service.finish_slot``, ``model.decode_step``,
-``transfer.logits_to_host``, ``cdf.build``, ``coder.step``,
-``rans.flush_slot`` and the rest of the ``PREFIXES`` families. Each idle
+``service.finish_slot``, ``model.decode_step``, ``cdf.build``,
+``transfer.cdf_to_host``, ``coder.step``, ``rans.flush_slot`` and the
+rest of the ``PREFIXES`` families. Each idle
 interval of the first device's busy union inside ``bench.traced`` is cut
 at the edges of those spans, and each piece goes to the innermost
 program span open over it, or to ``none`` outside every one (the client
@@ -30,8 +30,8 @@ OUTSIDE = "none"
 
 
 def bucket(span: str) -> str:
-    """The layer an idle piece is charged to: ``transfer`` (the logits'
-    and the CDFs' trips between host and device), ``coder`` (the host
+    """The layer an idle piece is charged to: ``transfer`` (the CDF
+    program and the trip of its ids and CDFs to the host), ``coder`` (the host
     rANS coder) or ``scheduler`` (all else: the step's own Python,
     refills, dispatch, and the client between polls)."""
     if span.startswith(("transfer.", "cdf.")):

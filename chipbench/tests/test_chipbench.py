@@ -23,8 +23,12 @@ FIX = pathlib.Path(__file__).resolve().parent / "fixtures"
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from chipbench import flops, harness, trace  # noqa: E402
+from chipbench.reference import dense  # noqa: E402
 
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+HOST_LAYERS = ("transfer_mb_per_step", "coder_host_ms", "scheduler_host_ms",
+               "step_compiles", "idle_transfer_ms", "idle_coder_ms",
+               "idle_scheduler_ms")
 
 
 def tiny_cell(name: str, **limits) -> dict:
@@ -42,25 +46,52 @@ def tiny_run(name: str, seed: int = 20240611, seconds: float = 2.0,
 
 
 # ------------------------------------------------------------------ files
-def test_loader_finds_new_config_mix_and_metric(tmp_path):
-    """A configuration, a traffic mix and a per-layer metric are added by
-    adding files and BENCHMARK.json entries; no existing file changes."""
-    shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench",
+# Reference modules a new configuration names, written as new files: the
+# dense decoder with its work counted three times, and one whose head is
+# scaled by 2 (a reference the program does not compute).
+REF_TIMES_3 = '''"""The dense decoder; its work counted three times."""
+from chipbench.reference import dense
+from chipbench.reference.dense import (PROGRAM_FIELDS, forward, make_weights,
+                                       mm, mm_int8, sample_documents)
+
+
+def flops_per_token(m, pos):
+    return 3 * dense.flops_per_token(m, pos)
+
+
+def decode_step_bytes(m, lanes, pos):
+    return 3 * dense.decode_step_bytes(m, lanes, pos)
+'''
+REF_HEAD_X2 = '''"""The dense decoder with its head scaled by 2."""
+from chipbench.reference import dense
+from chipbench.reference.dense import (PROGRAM_FIELDS, decode_step_bytes,
+                                       flops_per_token, make_weights, mm,
+                                       mm_int8, sample_documents)
+
+
+def forward(m, params, tokens, mm=mm):
+    return 2.0 * dense.forward(m, params, tokens, mm)
+'''
+
+
+def _add_tiny_cell(root: pathlib.Path, reference: str, source: str) -> str:
+    """Copy the benchmark under ``root`` and add, as new files and entries
+    only, a tiny configuration naming the reference module ``reference``
+    (written from ``source``), a deeper-queue mix, its cell's limits and a
+    per-layer metric; returns the new cell's name."""
+    shutil.copytree(ROOT / "chipbench", root / "chipbench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    before = {p: p.read_bytes()
-              for p in (tmp_path / "chipbench").rglob("*") if p.is_file()}
-
     conf = json.loads((FIX / "tiny.json").read_text())
-    (tmp_path / "chipbench/configs/tiny-new.json").write_text(
-        json.dumps(dict(conf, name="tiny-new")))
+    (root / f"chipbench/reference/{reference}.py").write_text(source)
+    (root / "chipbench/configs/tiny-new.json").write_text(
+        json.dumps(dict(conf, name="tiny-new", reference=reference)))
     mix = json.loads((ROOT / "chipbench/traffic/ingest.json").read_text())
     mix["outstanding_chunks_per_slot"] = 5
-    (tmp_path / "chipbench/traffic/deep-queue.json").write_text(
-        json.dumps(mix))
-    (tmp_path / "chipbench/limits/tiny-new.deep-queue.json").write_text(
+    (root / "chipbench/traffic/deep-queue.json").write_text(json.dumps(mix))
+    (root / "chipbench/limits/tiny-new.deep-queue.json").write_text(
         (FIX / "limits/tiny.ingest.json").read_text())
-    (tmp_path / "chipbench/metrics/refills_per_step.py").write_text(
+    (root / "chipbench/metrics/refills_per_step.py").write_text(
         "def read(rec):\n    return 7.0\n")
     bench["configs"].append({"name": "tiny-new", "source": "test",
                              "file": "chipbench/configs/tiny-new.json",
@@ -72,17 +103,142 @@ def test_loader_finds_new_config_mix_and_metric(tmp_path):
         "name": "refills_per_step", "unit": "ratio", "better": "higher",
         "source": "program_counter", "layer": "scheduler",
         "moves": "tokens_per_s", "workloads": ["tiny-new.deep-queue"]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return "tiny-new.deep-queue"
 
-    cell = harness.load_cell("tiny-new.deep-queue", root=tmp_path)
+
+def _run_keeping_record(name, cell, monkeypatch):
+    """``harness.run`` of ``cell`` on the CPU, and the record it drove."""
+    recs = []
+    drive = harness.drive
+
+    def keep(*a, **kw):
+        recs.append(drive(*a, **kw))
+        return recs[-1]
+    monkeypatch.setattr(harness, "drive", keep)
+    out = harness.run(name, 20240611, 2.0, False, t_start=time.perf_counter(),
+                      require_tpu=False, cell=cell)
+    return out, recs[0]
+
+
+def test_loader_finds_new_config_mix_and_metric(tmp_path, monkeypatch):
+    """A configuration, its plain reference, a traffic mix and a per-layer
+    metric are added by adding files and BENCHMARK.json entries; no
+    existing file changes. The cell runs end to end, checked against the
+    reference its configuration names, and the roofline and utilization
+    readers take that reference's work counts."""
+    before = {p: p.read_bytes()
+              for p in (ROOT / "chipbench").rglob("*")
+              if p.is_file() and "__pycache__" not in p.parts}
+    name = _add_tiny_cell(tmp_path, "tiny_times3", REF_TIMES_3)
+    for p, data in before.items():
+        copy = tmp_path / p.relative_to(ROOT)
+        assert copy.read_bytes() == data, f"{copy} differs"
+
+    cell = harness.load_cell(name, root=tmp_path)
     assert cell["config"]["name"] == "tiny-new"
     assert cell["traffic"]["outstanding_chunks_per_slot"] == 5
     assert [m["name"] for m in cell["per_layer"]] == ["refills_per_step"]
-    assert "tokens_per_s" in {m["name"] for m in cell["end_to_end"]}
+    assert {"tokens_per_s", "bits_per_token", "setup_s"} <= \
+        {m["name"] for m in cell["end_to_end"]}
     assert harness.metric_reader("refills_per_step",
                                  cell["bench_dir"])({}) == 7.0
+
+    out, rec = _run_keeping_record(name, cell, monkeypatch)
+    assert out["correct"], out["compared"]
+    assert rec["reference"] == "tiny_times3"
+    assert out["metrics"]["bits_per_token"]["value"] > 0
+    # the record's readers count the named module's work: three times
+    # the dense decoder's, for the same window
+    rec = dict(rec, peaks=harness.peaks_for("TPU v5 lite"),
+               trace={"programs": {"_decode": {"seconds": 0.9,
+                                               "count": 100}}})
+    for metric in ("mfu", "decode_roofline"):
+        read = harness.metric_reader(metric, cell["bench_dir"])
+        assert read(rec) == pytest.approx(
+            3 * read(dict(rec, reference="dense")), rel=1e-12), metric
     for p, data in before.items():
-        assert p.read_bytes() == data, f"{p} changed"
+        copy = tmp_path / p.relative_to(ROOT)
+        assert copy.read_bytes() == data, f"{copy} changed"
+
+
+def test_named_reference_decides_correct(tmp_path, monkeypatch):
+    """The check computes the reference the configuration names, not the
+    dense default: a reference with its head scaled by 2 gives code
+    lengths the program's do not match, and the run is not correct."""
+    name = _add_tiny_cell(tmp_path, "tiny_head_x2", REF_HEAD_X2)
+    cell = harness.load_cell(name, root=tmp_path)
+    out, rec = _run_keeping_record(name, cell, monkeypatch)
+    assert rec["reference"] == "tiny_head_x2"
+    assert out["correct"] is False
+    assert out["compared"]["failed_jobs"]["value"] == 0
+    assert out["compared"]["roundtrip_mismatched_tokens"]["value"] == 0
+    assert out["compared"]["abs_gap_bits_per_token"]["value"] > \
+        out["compared"]["abs_gap_bits_per_token"]["limit"]
+
+
+# The dense default as it computed before a configuration could name its
+# reference: work counts at mean position 127.5 over the cell's slots,
+# the program fields the files set, and on the fixtures' tiny model a
+# digest of the weight leaves and the code lengths of a fixed chunk set.
+DENSE_PINS = {
+    "qwen3-1.7b": (3470376960.0, 4411146240.0, {
+        "n_layers": 28, "d_model": 2048, "n_heads": 16, "n_kv_heads": 8,
+        "d_head": 128, "d_ff": 6144, "vocab_size": 151936,
+        "rope_theta": 1000000, "norm_eps": 1e-06, "tie_embeddings": True,
+        "qk_norm": True, "dtype": "bfloat16"}),
+    "deepseek-7b": (6941696000.0, 7935361024.0, {
+        "n_layers": 15, "d_model": 4096, "n_heads": 32, "n_kv_heads": 32,
+        "d_head": 128, "d_ff": 11008, "vocab_size": 102400,
+        "rope_theta": 10000, "norm_eps": 1e-06, "tie_embeddings": False,
+        "qk_norm": False, "dtype": "bfloat16"}),
+}
+TINY_WEIGHTS_SHA256 = \
+    "b8487c34e6451198817cb41104c19e1d5520a7a7bf3ee1e486dff2f93d7f07d7"
+TINY_CHUNK_BITS = [474.46408462524414, 261.0270414352417, 13.017898559570312,
+                   503.7782220840454, 76.62140083312988, 484.79073214530945,
+                   295.912145614624, 134.0672082901001]
+
+
+@pytest.mark.parametrize("name", sorted(DENSE_PINS))
+def test_dense_default_counts_and_fields_pinned(name):
+    import importlib
+    conf = json.loads((ROOT / f"chipbench/configs/{name}.json").read_text())
+    assert "reference" not in conf
+    assert harness.reference_module(conf) is dense
+    fl, by, fields = DENSE_PINS[name]
+    m, lanes = conf["model"], conf["service"]["slots"]
+    assert dense.flops_per_token(m, 127.5) == fl
+    assert dense.decode_step_bytes(m, lanes, 127.5) == by
+    peaks = harness.peaks_for("TPU v5 lite")
+    rec = {"reference": "dense", "model": m, "peaks": peaks,
+           "mean_pos": 127.5}
+    assert flops.decode_step_seconds(rec, lanes) == \
+        max(lanes * fl / 197e12, by / 819e9)
+    cfg = harness.program_config(conf)
+    base = importlib.import_module(conf["program"]["module"]).CONFIG
+    assert cfg == base.with_(**fields)
+
+
+def test_dense_default_weights_and_bits_pinned():
+    import hashlib
+    import jax
+    conf = json.loads((FIX / "tiny.json").read_text())
+    ref = harness.reference_module(conf)
+    m = harness.model_spec(conf, harness.program_config(conf))
+    params = ref.make_weights(m, conf["init"], conf["init"]["seed"])
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        a = np.asarray(leaf)
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(str(a.shape).encode())
+        h.update(a.view(np.uint16).tobytes())
+    assert h.hexdigest() == TINY_WEIGHTS_SHA256
+    chunks = np.random.default_rng(0).integers(
+        0, m["vocab_size"], (8, 32)).astype(np.int32)
+    valid = np.array([32, 17, 1, 32, 5, 32, 20, 9])
+    bits = harness.reference_bits(ref, conf, m, chunks, valid, 4)
+    np.testing.assert_allclose(bits, TINY_CHUNK_BITS, rtol=1e-6)
 
 
 def test_suffixed_metric_copies_share_a_reader():
@@ -124,53 +280,55 @@ def test_qwen3_flops_and_bytes_by_hand():
     # 2048x6144 = 4,194,304 x 2 + 2,097,152 x 2 + 37,748,736
     per_layer = 2 * 2048 * 2048 + 2 * 2048 * 1024 + 3 * 2048 * 6144
     assert per_layer == 50_331_648
-    assert flops.layer_params(m) == 28 * per_layer == 1_409_286_144
-    assert flops.head_params(m) == 2048 * 151936 == 311_164_928
-    assert flops.kv_bytes_per_position(m) == 2 * 28 * 8 * 128 * 2 == 114_688
+    assert dense.layer_params(m) == 28 * per_layer == 1_409_286_144
+    assert dense.head_params(m) == 2048 * 151936 == 311_164_928
+    assert dense.kv_bytes_per_position(m) == 2 * 28 * 8 * 128 * 2 == 114_688
     # a token at position 99: 2 x (1,409,286,144 + 311,164,928) matrix
     # FLOPs plus 4 x 28 layers x 16 heads x 128 x 100 keys for attention
-    assert flops.flops_per_token(m, 99) == \
+    assert dense.flops_per_token(m, 99) == \
         2 * 1_720_451_072 + 4 * 28 * 16 * 128 * 100
     # 64 lanes at mean position 127.5: weights + 64 embedding rows, the
     # cache read to each position and written at it, and the logits
     want = ((1_720_451_072 + 64 * 2048) * 2 + 64 * 129.5 * 114_688
             + 64 * 151936 * 2)
-    assert flops.decode_step_bytes(m, 64, 127.5) == want
+    assert dense.decode_step_bytes(m, 64, 127.5) == want
     peaks = harness.peaks_for("TPU v5 lite")
-    assert flops.decode_step_seconds(m, peaks, 64, 127.5) == \
-        pytest.approx(want / 819e9)
+    rec = {"reference": "dense", "model": m, "peaks": peaks,
+           "mean_pos": 127.5}
+    assert flops.decode_step_seconds(rec, 64) == pytest.approx(want / 819e9)
 
 
 def test_deepseek_flops_and_bytes_by_hand():
     m = _model("deepseek-7b")
     per_layer = 4 * 4096 * 4096 + 3 * 4096 * 11008
     assert per_layer == 202_375_168
-    assert flops.layer_params(m) == 15 * per_layer == 3_035_627_520
-    assert flops.head_params(m) == 4096 * 102400 == 419_430_400
-    assert flops.kv_bytes_per_position(m) == 2 * 15 * 32 * 128 * 2 \
+    assert dense.layer_params(m) == 15 * per_layer == 3_035_627_520
+    assert dense.head_params(m) == 4096 * 102400 == 419_430_400
+    assert dense.kv_bytes_per_position(m) == 2 * 15 * 32 * 128 * 2 \
         == 245_760
-    assert flops.flops_per_token(m, 0) == \
+    assert dense.flops_per_token(m, 0) == \
         2 * (3_035_627_520 + 419_430_400) + 4 * 15 * 32 * 128
     # the untied input embedding is read only at the lanes' rows
     want = ((3_455_057_920 + 32 * 4096) * 2 + 32 * 2 * 245_760
             + 32 * 102400 * 2)
-    assert flops.decode_step_bytes(m, 32, 0) == want
+    assert dense.decode_step_bytes(m, 32, 0) == want
 
 
 def test_mfu_and_roofline_readers():
     m = _model("qwen3-1.7b")
     peaks = harness.peaks_for("TPU v5 lite")
     rec = {"model": m, "peaks": peaks, "mean_pos": 127.5, "poll_s": 2.0,
+           "reference": "dense",
            "counters": {"token_steps": 6400, "model_steps": 100,
                         "lane_steps": 6400},
            "trace": {"programs": {"_decode": {"seconds": 0.9,
                                               "count": 100}}}}
     mfu = harness.metric_reader("mfu")(rec)
     assert mfu == pytest.approx(
-        100 * 6400 * flops.flops_per_token(m, 127.5) / (2.0 * 197e12))
+        100 * 6400 * dense.flops_per_token(m, 127.5) / (2.0 * 197e12))
     roof = harness.metric_reader("decode_roofline")(rec)
     assert roof == pytest.approx(
-        100 * flops.decode_step_seconds(m, peaks, 64, 127.5) / 0.009)
+        100 * flops.decode_step_seconds(rec, 64) / 0.009)
     rec["trace"] = None
     assert harness.metric_reader("decode_roofline")(rec) is None
 
@@ -242,7 +400,6 @@ def test_trace_reduction_by_hand():
 def test_reference_matches_program_forward(qk_norm, tied):
     import jax
     import jax.numpy as jnp
-    from chipbench.reference import dense
     from repro.configs.qwen3_1_7b import CONFIG
     from repro.models import api as model_api
 
@@ -272,7 +429,6 @@ def test_greedy_pool_follows_the_reference():
     model: greedy documents (top-1) are the reference forward's argmax
     at nearly every position (bfloat16 against float32 ties aside)."""
     import jax.numpy as jnp
-    from chipbench.reference import dense
     conf = json.loads((FIX / "tiny.json").read_text())
     m = dict(conf["model"], vocab_pad_multiple=1)
     params = dense.make_weights(m, conf["init"], 5)
@@ -347,6 +503,20 @@ def test_result_line_keys_and_correct(name):
     assert set(out["device"]) == {"platform", "kind", "count",
                                   "memory_peak_bytes"}
     json.dumps(out)
+
+
+def test_traced_run_reads_host_layers():
+    """A traced run's record carries the service registry's window deltas
+    and the program-span split of its trace: each host-layer reader reads
+    a value, and the window compiled nothing."""
+    out = harness.run("tiny.ingest", 20240611, 2.0, True,
+                      t_start=time.perf_counter(), require_tpu=False,
+                      cell=tiny_cell("tiny.ingest"))
+    assert out["correct"], out["compared"]
+    for name in HOST_LAYERS:
+        assert out["metrics"][name]["value"] >= 0, name
+    assert out["metrics"]["step_compiles"]["value"] == 0
+    assert out["metrics"]["transfer_mb_per_step"]["value"] > 0
 
 
 def test_every_seed_gets_the_same_work():

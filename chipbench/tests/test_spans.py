@@ -43,7 +43,7 @@ def _planes(host_events, ops):
 
 def test_idle_split_by_hand():
     """Window [0, 100). Busy [10, 30) and [60, 70). Step one [5, 50)
-    holds model [5, 25) > transfer [20, 25), then cdf [25, 35), coder
+    holds model [5, 20), then cdf [20, 35) > transfer [25, 30), coder
     [35, 45); step two [55, 90) holds model [55, 70) and coder [80, 88).
     Idle: [0, 10) outside (5) and model (5); [30, 60) cdf (5), coder
     (10), step (5), outside (5), model (5); [70, 100) step (10), coder
@@ -51,9 +51,9 @@ def test_idle_split_by_hand():
     and the stretch between them, and idle falls outside every
     service.step."""
     host = [("bench.traced", 0, 100), ("bench.poll", 5, 45),
-            ("service.step", 5, 45), ("model.decode_step", 5, 20),
-            ("transfer.logits_to_host", 20, 5), ("np.asarray", 20, 5),
-            ("cdf.build", 25, 10), ("coder.step", 35, 10),
+            ("service.step", 5, 45), ("model.decode_step", 5, 15),
+            ("cdf.build", 20, 15), ("transfer.cdf_to_host", 25, 5),
+            ("np.asarray", 25, 5), ("coder.step", 35, 10),
             ("service.step", 55, 35), ("model.decode_step", 55, 15),
             ("coder.step", 80, 8)]
     red = spans.reduce_planes(_planes(host, [(10, 20), (60, 10)]))
@@ -123,7 +123,7 @@ def _record():
                 "service.step": {"seconds": 3.0, "count": 100},
                 "service.step/model.decode_step": {"seconds": 1.2,
                                                    "count": 100},
-                "service.step/model.decode_step/transfer.logits_to_host":
+                "service.step/cdf.build/transfer.cdf_to_host":
                     {"seconds": 0.5, "count": 100},
                 "service.step/cdf.build": {"seconds": 0.6, "count": 100},
                 "service.step/coder.step": {"seconds": 0.3, "count": 100},
@@ -176,8 +176,9 @@ def test_layers_run_on_tiny_cell():
     assert steps > 0
     sp = out["registry"]["spans"]
     for path in ("service.step", "service.step/model.decode_step",
-                 "service.step/model.decode_step/transfer.logits_to_host",
-                 "service.step/cdf.build", "service.step/coder.step"):
+                 "service.step/cdf.build",
+                 "service.step/cdf.build/transfer.cdf_to_host",
+                 "service.step/coder.step"):
         assert sp[path]["count"] == steps, path
     red = out["spans"]
     assert red["idle_s"] == pytest.approx(red["window_s"])   # no device
