@@ -1,7 +1,7 @@
 """Model/run configuration.
 
-One `ModelConfig` covers all six assigned families (dense / moe / ssm /
-hybrid / encdec / vlm); family-specific fields are zero/None when unused.
+One `ModelConfig` covers every family (dense / moe / ssm / hybrid /
+hybrid_moe / encdec / vlm); family-specific fields are zero/None when unused.
 `ShapeConfig` describes the four assigned input-shape cells.
 """
 from __future__ import annotations
@@ -10,7 +10,7 @@ import math
 from dataclasses import dataclass, field, replace
 from typing import Optional
 
-FAMILIES = ("dense", "moe", "ssm", "hybrid", "encdec", "vlm")
+FAMILIES = ("dense", "moe", "ssm", "hybrid", "hybrid_moe", "encdec", "vlm")
 
 
 @dataclass(frozen=True)
@@ -42,6 +42,21 @@ class ModelConfig:
     # hybrid (zamba2): groups of `hybrid_ssm_per_block` ssm layers, each
     # followed by ONE application of a single shared attention block.
     hybrid_ssm_per_block: int = 0
+    # hybrid_moe (granite-4.0-h): the mixer of each layer, "mamba" or
+    # "attention" (the first n_layers entries), each followed by routed
+    # experts plus a shared SwiGLU expert of width shared_d_ff
+    layer_types: tuple = ()
+    shared_d_ff: int = 0
+    experts_held: int = 0         # routed experts held here; 0 = all
+    position_embedding: str = "rope"      # "rope" | "nope"
+    attn_scale: Optional[float] = None    # softmax scale; None = 1/sqrt(hd)
+    # muP multipliers: input embedding, both residual branches, logits
+    # (divided); 1.0 leaves a program as it is without them
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    ssm_conv_bias: bool = False
+    ssm_gated_norm: bool = False  # RMSNorm(y * silu(z)) * w before out_proj
     # encdec (whisper): n_layers is the decoder depth; encoder depth below.
     n_enc_layers: int = 0
     max_source_len: int = 1500
@@ -166,8 +181,12 @@ def tiny_variant(cfg: ModelConfig, **overrides) -> ModelConfig:
     )
     if cfg.family == "moe":
         kw.update(n_experts=4, top_k=2, d_ff=32)
-    if cfg.family in ("ssm", "hybrid"):
+    if cfg.family in ("ssm", "hybrid", "hybrid_moe"):
         kw.update(ssm_state=16, ssm_headdim=16, ssm_chunk=16, ssm_expand=2)
+    if cfg.family == "hybrid_moe":
+        kw.update(n_layers=3, layer_types=("mamba", "attention", "mamba"),
+                  n_experts=16, top_k=4, experts_held=2, d_ff=32,
+                  shared_d_ff=48, attn_scale=1.0 / 16)
     if cfg.family == "hybrid":
         kw.update(n_layers=4, hybrid_ssm_per_block=2)
     if cfg.family == "encdec":

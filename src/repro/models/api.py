@@ -1,5 +1,5 @@
 """Family dispatch: a single forward/init_cache/decode_step API over the
-six model families."""
+model families."""
 from __future__ import annotations
 
 from typing import Any
@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from . import encdec, hybrid, mamba2, moe, transformer
+from . import encdec, hybrid, hybrid_moe, mamba2, moe, transformer
 from .schema import abstract_params, count_params, init_params, param_axes
 
 _FAMS = {
@@ -17,8 +17,15 @@ _FAMS = {
     "moe": moe,
     "ssm": mamba2,
     "hybrid": hybrid,
+    "hybrid_moe": hybrid_moe,
     "encdec": encdec,
 }
+# families whose serving paths route over experts: dropless dispatch, so
+# scoring and decoding give the same distributions (models/moe.py)
+DROPLESS = ("moe", "hybrid_moe")
+# families whose decode_step(..., stats=True) also returns the step's
+# counters (name -> device scalar)
+STEP_STATS = ("hybrid_moe",)
 
 
 def module_for(cfg: ModelConfig):

@@ -87,6 +87,14 @@ def residual_barrier(x):
     return x
 
 
+def scale_residual(cfg, y):
+    """A residual branch's output times ``cfg.residual_multiplier`` (muP);
+    at 1.0 the branch is returned as it is, so programs without the
+    multiplier do not change."""
+    m = cfg.residual_multiplier
+    return y if m == 1.0 else y * m
+
+
 def rms_norm(x, weight, eps: float = 1e-6):
     dt = x.dtype
     x = x.astype(jnp.float32)
@@ -160,7 +168,7 @@ def _mask_bias(q_pos, k_pos, *, causal, window):
 
 
 def attention_masked(q, k, v, *, causal=True, window=None,
-                     q_offset=0, k_offset=0, q_chunk=512):
+                     q_offset=0, k_offset=0, q_chunk=512, scale=None):
     """Baseline attention: scan over q chunks, each attends the full KV with
     an additive mask; online softmax keeps memory at O(q_chunk * Sk).
 
@@ -169,7 +177,7 @@ def attention_masked(q, k, v, *, causal=True, window=None,
     B, Sq0, H, hd = q.shape
     _, Sk, K, _ = k.shape
     G = H // K
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     qc = min(q_chunk, Sq0)
     if Sq0 % qc:  # pad q rows; padded rows are sliced off the output
         q = jnp.pad(q, ((0, 0), (0, qc - Sq0 % qc), (0, 0), (0, 0)))
@@ -196,7 +204,7 @@ def attention_masked(q, k, v, *, causal=True, window=None,
 
 
 def attention_block_causal(q, k, v, *, causal=True, window=None,
-                           q_offset=0, k_offset=0, q_chunk=512):
+                           q_offset=0, k_offset=0, q_chunk=512, scale=None):
     """Block-sparse causal attention: a scan over only the (qi, kj) chunk
     pairs that contain unmasked entries. Cuts the masked-dense FLOP waste
     (~2x for causal, more for SWA). Online softmax across kv blocks.
@@ -204,7 +212,7 @@ def attention_block_causal(q, k, v, *, causal=True, window=None,
     B, Sq0, H, hd = q.shape
     _, Sk0, K, _ = k.shape
     G = H // K
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     qc = min(q_chunk, Sq0)
     if Sq0 % qc:
         q = jnp.pad(q, ((0, 0), (0, qc - Sq0 % qc), (0, 0), (0, 0)))
@@ -257,7 +265,7 @@ def attention_block_causal(q, k, v, *, causal=True, window=None,
     return out[:, :Sq0].astype(q.dtype)
 
 
-def decode_attention(q, k_cache, v_cache, pos, *, window=None):
+def decode_attention(q, k_cache, v_cache, pos, *, window=None, scale=None):
     """Single-step attention over a preallocated KV cache.
 
     q (B,1,H,hd); caches (B,S,K,hd); pos () or (B,) int32 = index of the
@@ -271,7 +279,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=None):
     B, _, H, hd = q.shape
     _, S, K, _ = k_cache.shape
     G = H // K
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     qg = q.reshape(B, K, G, hd)
     s = jnp.einsum("bkgh,bskh->bkgs", qg.astype(jnp.float32),
                    k_cache.astype(jnp.float32)) * scale
@@ -288,7 +296,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=None):
 
 
 def attention_dense(q, k, v, *, causal=True, window=None,
-                    q_offset=0, k_offset=0, q_chunk=None):
+                    q_offset=0, k_offset=0, q_chunk=None, scale=None):
     """Loop-free masked attention (single einsum chain). Used by the
     dry-run COST PROBES: XLA's HloCostAnalysis counts while-loop bodies
     once, so probes must not contain loops. Memory-naive (materializes
@@ -298,7 +306,8 @@ def attention_dense(q, k, v, *, causal=True, window=None,
     G = H // K
     s = jnp.einsum("bqkgh,bskh->bkgqs",
                    q.reshape(B, Sq, K, G, hd).astype(jnp.float32),
-                   k.astype(jnp.float32)) / math.sqrt(hd)
+                   k.astype(jnp.float32))
+    s = s / math.sqrt(hd) if scale is None else s * scale
     bias = _mask_bias(q_offset + jnp.arange(Sq), k_offset + jnp.arange(Sk),
                       causal=causal, window=window)
     s = s + bias

@@ -5,8 +5,11 @@ also implemented as a Pallas kernel, kernels/ssd_scan.py), and the O(1)
 recurrent decode step.
 
 Per layer:  x -> [z | xc | B | C | dt] projections; causal conv1d over
-(xc,B,C); SSD recurrence with per-head scalar decay A; gated output.
-State per head: (P, N) with P=headdim, N=ssm_state.
+(xc,B,C) (plus a bias with ``cfg.ssm_conv_bias``); SSD recurrence with
+per-head scalar decay A; gated output y * silu(z) (with
+``cfg.ssm_gated_norm``, RMSNorm of that product times its own weight, as
+Granite-4.0-H gates it). State per head: (P, N) with P=headdim,
+N=ssm_state.
 """
 from __future__ import annotations
 
@@ -14,12 +17,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from .layers import rms_norm, shard
+from .layers import rms_norm, scale_residual, shard
 
 
-def _conv1d_causal(x, w, state=None):
-    """Causal depthwise conv. x (B,S,C), w (K,C). If `state` (B,K-1,C) is
-    given, it prefixes x (for decode); returns (y, new_state)."""
+def _conv1d_causal(x, w, state=None, bias=None):
+    """Causal depthwise conv. x (B,S,C), w (K,C), bias (C,) or None. If
+    `state` (B,K-1,C) is given, it prefixes x (for decode); returns
+    (y, new_state)."""
     K = w.shape[0]
     if state is None:
         pad = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype)
@@ -27,6 +31,8 @@ def _conv1d_causal(x, w, state=None):
         pad = state
     xp = jnp.concatenate([pad, x], axis=1)
     y = sum(xp[:, i:i + x.shape[1]] * w[i] for i in range(K))
+    if bias is not None:
+        y = y + bias
     new_state = xp[:, -(K - 1):]
     return jax.nn.silu(y), new_state
 
@@ -97,6 +103,15 @@ def ssd_chunked(x, dt, A, Bm, Cm, *, chunk: int, initial_state=None):
     return y.astype(x.dtype), final.astype(x.dtype)
 
 
+def _gate(cfg: ModelConfig, lp: dict, y, z, dtype):
+    """The SSM output gated by z: y * silu(z), or with ``ssm_gated_norm``
+    RMSNorm(y * silu(z)) * lp["gate_norm"] computed in float32."""
+    if cfg.ssm_gated_norm:
+        g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        return rms_norm(g, lp["gate_norm"], cfg.norm_eps).astype(dtype)
+    return (y * jax.nn.silu(z)).astype(dtype)
+
+
 def ssm_block(cfg: ModelConfig, lp: dict, x):
     """Full mamba2 layer (training/prefill). x (B,S,D) -> (B,S,D)."""
     B, S, D = x.shape
@@ -108,7 +123,7 @@ def ssm_block(cfg: ModelConfig, lp: dict, x):
     Cm = jnp.einsum("bsd,dn->bsn", h, lp["in_C"])
     dt_raw = jnp.einsum("bsd,dh->bsh", h, lp["in_dt"])
     conv_in = jnp.concatenate([xc, Bm, Cm], axis=-1)
-    conv_out, _ = _conv1d_causal(conv_in, lp["conv_w"])
+    conv_out, _ = _conv1d_causal(conv_in, lp["conv_w"], bias=lp.get("conv_b"))
     xc, Bm, Cm = jnp.split(conv_out, [di, di + N], axis=-1)
     xc = shard(xc, ("pod", "data"), None, None)
     dt = jax.nn.softplus(dt_raw + lp["dt_bias"])
@@ -116,8 +131,8 @@ def ssm_block(cfg: ModelConfig, lp: dict, x):
     y, _ = ssd_chunked(xc.reshape(B, S, H, Pd), dt, A, Bm, Cm,
                        chunk=cfg.ssm_chunk)
     y = y + lp["D_skip"][None, None, :, None] * xc.reshape(B, S, H, Pd)
-    y = (y.reshape(B, S, di) * jax.nn.silu(z)).astype(x.dtype)
-    return x + jnp.einsum("bse,ed->bsd", y, lp["out_proj"])
+    y = _gate(cfg, lp, y.reshape(B, S, di), z, x.dtype)
+    return x + scale_residual(cfg, jnp.einsum("bse,ed->bsd", y, lp["out_proj"]))
 
 
 # ------------------------------------------------------------------- decode
@@ -141,7 +156,8 @@ def ssm_decode_step(cfg: ModelConfig, lp: dict, x, conv_state, ssm_state):
     Cm = jnp.einsum("bsd,dn->bsn", h, lp["in_C"])
     dt_raw = jnp.einsum("bsd,dh->bsh", h, lp["in_dt"])
     conv_in = jnp.concatenate([xc, Bm, Cm], axis=-1)
-    conv_out, conv_state = _conv1d_causal(conv_in, lp["conv_w"], conv_state)
+    conv_out, conv_state = _conv1d_causal(conv_in, lp["conv_w"], conv_state,
+                                          lp.get("conv_b"))
     xc, Bm, Cm = jnp.split(conv_out[:, 0], [di, di + N], axis=-1)
     dt = jax.nn.softplus(dt_raw[:, 0] + lp["dt_bias"])            # (B,H)
     A = -jnp.exp(lp["A_log"].astype(jnp.float32))
@@ -152,8 +168,9 @@ def ssm_decode_step(cfg: ModelConfig, lp: dict, x, conv_state, ssm_state):
     ssm_state = ssm_state * dA[..., None, None] + upd.astype(jnp.float32)
     y = jnp.einsum("bhpn,bn->bhp", ssm_state.astype(x.dtype), Cm)
     y = y + lp["D_skip"][None, :, None] * xh
-    y = (y.reshape(B, 1, di) * jax.nn.silu(z)).astype(x.dtype)
-    return x + jnp.einsum("bse,ed->bsd", y, lp["out_proj"]), conv_state, ssm_state
+    y = _gate(cfg, lp, y.reshape(B, 1, di), z, x.dtype)
+    out = scale_residual(cfg, jnp.einsum("bse,ed->bsd", y, lp["out_proj"]))
+    return x + out, conv_state, ssm_state
 
 
 def forward(params, cfg: ModelConfig, batch: dict, *, return_hidden=False, **_):

@@ -60,9 +60,16 @@ def _expert_ffn(buf, w_gate, w_up, w_down):
 
 
 def moe_ffn_local(x_flat, lp, cfg: ModelConfig, *, shard_id=0, n_shards=1,
-                  gathered=None, dropless=False):
+                  gathered=None, dropless=False, count=False):
     """Dispatch + expert compute for the experts owned by `shard_id`.
-    Returns the *partial* output (full output iff n_shards == 1).
+    Returns the *partial* output (full output iff n_shards == 1); with
+    `count`, also the number of (token, slot) pairs its experts computed.
+
+    The router always scores all cfg.n_experts; the expert weights hold
+    only this shard's E / n_shards experts. On one chip with no mesh this
+    is the expert layer of a chip that holds a share of the experts
+    (cfg.experts_held): it computes its own experts' part for the tokens
+    routed to them, and what the other shards would add is left out.
 
     dropless=True sets capacity C = T: since top-k experts are distinct per
     token, no expert can receive more than T tokens, so nothing is ever
@@ -94,7 +101,10 @@ def moe_ffn_local(x_flat, lp, cfg: ModelConfig, *, shard_id=0, n_shards=1,
         [out_buf.reshape(E_loc * C, D), jnp.zeros((1, D), out_buf.dtype)], 0)
     y_slots = out_buf[dest] * (weights.reshape(-1)[:, None] *
                                keep[:, None]).astype(out_buf.dtype)
-    return jnp.sum(y_slots.reshape(T, k, D), axis=1)
+    y = jnp.sum(y_slots.reshape(T, k, D), axis=1)
+    if count:
+        return y, jnp.sum(keep, dtype=jnp.int32)
+    return y
 
 
 def moe_block(cfg: ModelConfig, lp: dict, x, *, mesh=None, dropless=False,

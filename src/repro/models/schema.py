@@ -67,7 +67,10 @@ def _mlp_leaves(cfg: ModelConfig, L: Optional[int]) -> dict:
 
 
 def _moe_leaves(cfg: ModelConfig, L: int) -> dict:
+    """Router over all n_experts; expert weights for the experts held
+    here (cfg.experts_held, 0 = all)."""
     D, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    Eh = cfg.experts_held or E
     s_in, s_out = 1.0 / np.sqrt(D), 1.0 / np.sqrt(F)
     # Experts take the TP ('model') axis => per-expert F stays unsharded;
     # D rows keep the FSDP ('embed' -> data) axis.
@@ -75,9 +78,9 @@ def _moe_leaves(cfg: ModelConfig, L: int) -> dict:
     # layout (resident experts would not fit HBM) — see sharding/specs.py.
     return {
         "router": Leaf((L, D, E), ("layers", "embed", None), "normal", s_in),
-        "we_gate": Leaf((L, E, D, F), ("layers", "expert", "expert_embed", None), "normal", s_in),
-        "we_up": Leaf((L, E, D, F), ("layers", "expert", "expert_embed", None), "normal", s_in),
-        "we_down": Leaf((L, E, F, D), ("layers", "expert", None, "expert_embed"), "normal", s_out),
+        "we_gate": Leaf((L, Eh, D, F), ("layers", "expert", "expert_embed", None), "normal", s_in),
+        "we_up": Leaf((L, Eh, D, F), ("layers", "expert", "expert_embed", None), "normal", s_in),
+        "we_down": Leaf((L, Eh, F, D), ("layers", "expert", None, "expert_embed"), "normal", s_out),
     }
 
 
@@ -85,7 +88,7 @@ def _ssm_leaves(cfg: ModelConfig, L: int) -> dict:
     D = cfg.d_model
     di, N, Hs, KC = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_conv
     s = 1.0 / np.sqrt(D)
-    return {
+    leaves = {
         "ln": Leaf((L, D), ("layers", None), "ones"),
         "in_z": Leaf((L, D, di), ("layers", "embed", "ssm_inner"), "normal", s),
         "in_x": Leaf((L, D, di), ("layers", "embed", "ssm_inner"), "normal", s),
@@ -99,6 +102,12 @@ def _ssm_leaves(cfg: ModelConfig, L: int) -> dict:
         "out_proj": Leaf((L, di, D), ("layers", "ssm_inner", "embed"),
                          "normal", 1.0 / np.sqrt(di)),
     }
+    if cfg.ssm_conv_bias:
+        leaves["conv_b"] = Leaf((L, di + 2 * N), ("layers", "ssm_inner"),
+                                "zeros")
+    if cfg.ssm_gated_norm:
+        leaves["gate_norm"] = Leaf((L, di), ("layers", "ssm_inner"), "ones")
+    return leaves
 
 
 def _norm(L: Optional[int], name: str, D: int) -> dict:
@@ -136,6 +145,20 @@ def schema(cfg: ModelConfig) -> dict:
         tree["layers"] = _ssm_leaves(cfg, L)
         tree["shared_attn"] = {**_attn_leaves(cfg, None), **_mlp_leaves(cfg, None),
                                **_norm(None, "ln1", D), **_norm(None, "ln2", D)}
+    elif cfg.family == "hybrid_moe":
+        # stacked by kind: mixers in layer order within their kind, and one
+        # expert FFN (routed + shared) per layer
+        kinds = cfg.layer_types[:L]
+        n_attn = kinds.count("attention")
+        tree["mamba"] = _ssm_leaves(cfg, L - n_attn)
+        tree["attn"] = {**_attn_leaves(cfg, n_attn), **_norm(n_attn, "ln1", D)}
+        Fs = cfg.shared_d_ff
+        tree["moe"] = {
+            **_moe_leaves(cfg, L), **_norm(L, "ln2", D),
+            "ws_gate": Leaf((L, D, Fs), ("layers", "embed", "mlp"), "normal", 1.0 / np.sqrt(D)),
+            "ws_up": Leaf((L, D, Fs), ("layers", "embed", "mlp"), "normal", 1.0 / np.sqrt(D)),
+            "ws_down": Leaf((L, Fs, D), ("layers", "mlp", "embed"), "normal", 1.0 / np.sqrt(Fs)),
+        }
     elif cfg.family == "encdec":
         Le = cfg.n_enc_layers
         tree["enc_layers"] = {**_attn_leaves(cfg, Le), **_mlp_leaves(cfg, Le),

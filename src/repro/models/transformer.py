@@ -22,7 +22,9 @@ def attn_block(cfg: ModelConfig, lp: dict, x, *, positions,
                attn_impl="masked", prefix="", kv_override=None,
                causal=True, q_chunk=512):
     """Pre-norm attention block (residual applied by caller).
-    kv_override: (k, v, kv_positions) for cross-attention."""
+    kv_override: (k, v, kv_positions) for cross-attention. positions=None
+    applies no rotary embedding (NoPE models, or absolute positions handled
+    outside)."""
     B, S, D = x.shape
     hd, Hp, Kp = cfg.head_dim, cfg.padded_heads, cfg.padded_kv_heads
     q = jnp.einsum("bsd,dh->bsh", x, lp[f"w{prefix}q"]).reshape(B, S, Hp, hd)
@@ -42,7 +44,8 @@ def attn_block(cfg: ModelConfig, lp: dict, x, *, positions,
     q = shard(q, ("pod", "data"), None, "model", None)
     k = shard(k, ("pod", "data"), None, None, None)
     impl = ATTN_IMPLS[attn_impl]
-    o = impl(q, k, v, causal=causal, window=cfg.sliding_window, q_chunk=q_chunk)
+    o = impl(q, k, v, causal=causal, window=cfg.sliding_window, q_chunk=q_chunk,
+             scale=cfg.attn_scale)
     o = o.reshape(B, S, Hp * hd)
     return tp_down_proj(o, lp[f"w{prefix}o"]), (k, v)
 
@@ -305,7 +308,9 @@ def _decode_attn_one(cfg, lp, x, kc, vc, pos, prefix="", scales=None):
     ``pos`` is (B,): each lane reads/writes its own cache position
     (scatter update + per-lane causal mask), which is what lets the
     service scheduler hold lanes at different chunk offsets. With all
-    lanes equal this computes exactly what the old scalar-pos path did."""
+    lanes equal this computes exactly what the old scalar-pos path did.
+    q and k are rotated unless ``cfg.position_embedding`` is "nope"; the
+    softmax scale is ``cfg.attn_scale`` (default 1/sqrt(head_dim))."""
     B, _, D = x.shape
     hd, Hp, Kp = cfg.head_dim, cfg.padded_heads, cfg.padded_kv_heads
     q = jnp.einsum("bsd,dh->bsh", x, lp[f"w{prefix}q"]).reshape(B, 1, Hp, hd)
@@ -314,8 +319,9 @@ def _decode_attn_one(cfg, lp, x, kc, vc, pos, prefix="", scales=None):
     if cfg.qk_norm and not prefix:
         q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-    q = rope(q, pos[:, None], cfg.rope_theta)
-    k = rope(k, pos[:, None], cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = rope(q, pos[:, None], cfg.rope_theta)
+        k = rope(k, pos[:, None], cfg.rope_theta)
     S = kc.shape[1]
     mesh_ss = _use_seq_sharded_decode(cfg) if not prefix else None
     if mesh_ss is not None:
@@ -351,7 +357,7 @@ def _decode_attn_one(cfg, lp, x, kc, vc, pos, prefix="", scales=None):
         abs_pos = pos[:, None] - jnp.mod(pos[:, None] - s_idx[None, :], S)
         o = _ring_attention(q, k_eff, v_eff, abs_pos >= 0)
     else:
-        o = decode_attention(q, k_eff, v_eff, pos)
+        o = decode_attention(q, k_eff, v_eff, pos, scale=cfg.attn_scale)
     o = o.reshape(B, 1, Hp * hd)
     out = tp_down_proj(o, lp[f"w{prefix}o"])
     if scales is not None:

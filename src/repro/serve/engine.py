@@ -17,7 +17,10 @@ fresh context per chunk — exactly the paper's chunked setup (§5.4).
 
 For MoE models both paths run dropless dispatch (see models/moe.py) so
 scoring and decoding produce bit-identical distributions — the lossless
-requirement.
+requirement. A family whose decode program also counts its own work
+(``model_api.STEP_STATS``) returns those counters as one more output;
+``decode_step`` keeps them on the device in ``step_stats`` for the
+service to fetch with the step's CDFs.
 """
 from __future__ import annotations
 
@@ -51,9 +54,13 @@ class ModelPredictor:
         self.bos_id = bos_id if bos_id is not None else cfg.vocab_size - 1
         self.extra_batch = extra_batch or {}
         self.mesh = mesh
-        fam_kw = {"dropless": True} if cfg.family == "moe" else {}
+        fam_kw = {"dropless": True} if cfg.family in model_api.DROPLESS \
+            else {}
         if cfg.family == "moe" and mesh is not None:
             fam_kw["mesh"] = mesh
+        stats_kw = {"stats": True} if cfg.family in model_api.STEP_STATS \
+            else {}
+        self.step_stats = None
 
         # jax.named_scope labels below mirror the host-span names (minus
         # the dots XProf dislikes) so a captured device trace interleaves
@@ -72,9 +79,9 @@ class ModelPredictor:
         @jax.jit
         def _decode(params, cache, prev, extra):
             with jax.named_scope("model_decode_step"):
-                logits, cache = model_api.decode_step(params, cfg, cache,
-                                                      prev, **fam_kw)
-                return logits[..., :cfg.vocab_size], cache
+                logits, cache, *stats = model_api.decode_step(
+                    params, cfg, cache, prev, **fam_kw, **stats_kw)
+                return (logits[..., :cfg.vocab_size], cache, *stats)
 
         @jax.jit
         def _score_ctx(params, tokens, prefix, extra):
@@ -272,11 +279,15 @@ class ModelPredictor:
         next state. The logits are the ``jax.Array`` the program writes:
         nothing waits for it and nothing is copied to the host, so the
         service hands them straight to its CDF program. A caller that
-        reads them on the host converts them (``np.asarray``)."""
+        reads them on the host converts them (``np.asarray``). The step's
+        counters, for a family that returns them, stay on the device in
+        ``step_stats`` until the next step."""
         prev = np.asarray(prev_tokens, np.int32)
         _count_bytes("transfer.h2d_bytes", prev)
-        return self._decode(self.params, state, jnp.asarray(prev),
-                            self.extra_batch)
+        logits, state, *stats = self._decode(
+            self.params, state, jnp.asarray(prev), self.extra_batch)
+        self.step_stats = stats[0] if stats else None
+        return logits, state
 
     def verify_steps(self, state, seq: np.ndarray):
         """Speculative-decode verify program: score seq (B, T) — column 0

@@ -518,13 +518,21 @@ class SlotScheduler:
         """Wait for the CDF program's outputs ``outs`` (and the model
         program ahead of it), then copy them to the host in one
         ``device_get`` inside ``transfer.cdf_to_host``, so that span
-        times the copy alone. Host logits, from an adapter that returns
-        them, were uploaded into the CDF program and count as sent."""
+        times the copy alone. The model step's own counters (the
+        predictor's ``step_stats``, name -> device scalar, where it has
+        them) come in the same ``device_get`` and are added to the
+        registry's counters of those names. Host logits, from an adapter
+        that returns them, were uploaded into the CDF program and count
+        as sent."""
+        stats = getattr(self.predictor, "step_stats", None) if tel else None
         jax.block_until_ready(outs)
         with obs.span("transfer.cdf_to_host", self.registry):
-            outs = jax.device_get(outs)
+            outs, stats = jax.device_get((outs, stats))
         if tel:
             self._c_d2h.inc(sum(o.nbytes for o in outs))
+            for name, v in (stats or {}).items():
+                self._c_d2h.inc(v.nbytes)
+                self.registry.counter(name).inc(int(v))
             if not isinstance(logits, jax.Array):
                 self._c_h2d.inc(logits.nbytes)
         return outs

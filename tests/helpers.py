@@ -10,6 +10,9 @@ BASE = dict(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
 
 
 def tiny(family="dense", **kw):
+    if family == "hybrid_moe":     # Granite-4.0-H's block, 2 of 16 experts
+        from repro.configs.granite_4_0_h_small import SMOKE_CONFIG
+        return SMOKE_CONFIG.with_(**dict(BASE, n_layers=3, d_ff=32, **kw))
     base = dict(BASE)
     if family == "moe":
         base.update(n_experts=4, top_k=2)

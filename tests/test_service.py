@@ -204,7 +204,7 @@ def test_scheduler_beats_grouped_steps_on_ragged():
 
 
 # -------------------------------------------------------------- real model
-@pytest.mark.parametrize("family", ["dense", "hybrid"])
+@pytest.mark.parametrize("family", ["dense", "hybrid", "hybrid_moe"])
 def test_slot_reset_bit_exact_mid_stream(family):
     """reset_slots on a mid-stream batch reproduces fresh-cache logits
     bit-exactly on the reset lanes — the primitive continuous batching
