@@ -26,6 +26,11 @@ DROPLESS = ("moe", "hybrid_moe")
 # families whose decode_step(..., stats=True) also returns the step's
 # counters (name -> device scalar)
 STEP_STATS = ("hybrid_moe",)
+# families whose decode_step writes its new K/V rows into the cache it is
+# given (the layer scan carries the stacked cache): ModelPredictor donates
+# the cache to their decode program. The others pass each layer's cache
+# through their scans' inputs and outputs, where donation only adds a copy.
+IN_PLACE_DECODE = ("dense", "vlm")
 
 
 def module_for(cfg: ModelConfig):

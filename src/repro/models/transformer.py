@@ -301,16 +301,10 @@ def _seq_sharded_decode_attn(cfg, mesh, q, k_new, v_new, kc, vc, pos,
     return o, kc, vc, None
 
 
-def _decode_attn_one(cfg, lp, x, kc, vc, pos, prefix="", scales=None):
-    """One-token attention vs. a (B,S,K,hd) cache; returns out, new kc/vc
-    (+ new scales when the cache is int8-quantized).
-
-    ``pos`` is (B,): each lane reads/writes its own cache position
-    (scatter update + per-lane causal mask), which is what lets the
-    service scheduler hold lanes at different chunk offsets. With all
-    lanes equal this computes exactly what the old scalar-pos path did.
-    q and k are rotated unless ``cfg.position_embedding`` is "nope"; the
-    softmax scale is ``cfg.attn_scale`` (default 1/sqrt(head_dim))."""
+def _decode_qkv(cfg, lp, x, pos, prefix=""):
+    """One token's q (B,1,Hp,hd) and k, v (B,1,Kp,hd): projected, qk-normed
+    when the config says so, and rotated at each lane's ``pos`` unless
+    ``cfg.position_embedding`` is "nope"."""
     B, _, D = x.shape
     hd, Hp, Kp = cfg.head_dim, cfg.padded_heads, cfg.padded_kv_heads
     q = jnp.einsum("bsd,dh->bsh", x, lp[f"w{prefix}q"]).reshape(B, 1, Hp, hd)
@@ -322,34 +316,33 @@ def _decode_attn_one(cfg, lp, x, kc, vc, pos, prefix="", scales=None):
     if cfg.position_embedding == "rope":
         q = rope(q, pos[:, None], cfg.rope_theta)
         k = rope(k, pos[:, None], cfg.rope_theta)
-    S = kc.shape[1]
-    mesh_ss = _use_seq_sharded_decode(cfg) if not prefix else None
-    if mesh_ss is not None:
-        o, kc, vc, new_scales = _seq_sharded_decode_attn(
-            cfg, mesh_ss, q, k, v, kc, vc, pos, scales=scales)
-        o = o.reshape(B, 1, Hp * hd)
-        out = tp_down_proj(o, lp[f"w{prefix}o"])
-        if scales is not None:
-            return out, kc, vc, new_scales
-        return out, kc, vc
-    slot = _cache_slot(cfg, pos, S)                     # (B,)
-    lanes = jnp.arange(B)
-    new_scales = None
-    if scales is not None:      # int8 cache path
-        ks, vs = scales
-        kq, k_sc = _quant_kv(k)
-        vq, v_sc = _quant_kv(v)
-        kc = kc.at[lanes, slot].set(kq[:, 0])
-        vc = vc.at[lanes, slot].set(vq[:, 0])
-        ks = ks.at[lanes, slot].set(k_sc[:, 0])
-        vs = vs.at[lanes, slot].set(v_sc[:, 0])
-        new_scales = (ks, vs)
-        k_eff = _dequant_kv(kc, ks).astype(x.dtype)
-        v_eff = _dequant_kv(vc, vs).astype(x.dtype)
-    else:
-        kc = kc.at[lanes, slot].set(k[:, 0].astype(kc.dtype))
-        vc = vc.at[lanes, slot].set(v[:, 0].astype(vc.dtype))
-        k_eff, v_eff = kc, vc
+    return q, k, v
+
+
+def _kv_rows(k, v, int8):
+    """The (B, 1, ...) rows one step writes into a K/V cache: k and v, or
+    under an int8 cache k and v quantized with their scales."""
+    if not int8:
+        return k, v
+    kq, k_sc = _quant_kv(k)
+    vq, v_sc = _quant_kv(v)
+    return kq, vq, k_sc, v_sc
+
+
+def _kv_read(leaves, dtype):
+    """The k and v attention reads from a layer's cache leaves (k, v) or
+    (k, v, k_scale, v_scale)."""
+    if len(leaves) == 2:
+        return leaves
+    kc, vc, ks, vs = leaves
+    return (_dequant_kv(kc, ks).astype(dtype),
+            _dequant_kv(vc, vs).astype(dtype))
+
+
+def _decode_attend(cfg, lp, q, k_eff, v_eff, pos, prefix=""):
+    """Attention of one token over a layer's (B,S,K,hd) cache that already
+    holds the token's own row, then the output projection."""
+    B, S = k_eff.shape[:2]
     if cfg.sliding_window:
         # ring buffer: slot s holds abs position pos - ((pos - s) mod S);
         # valid if >= 0 — computed per lane
@@ -358,11 +351,59 @@ def _decode_attn_one(cfg, lp, x, kc, vc, pos, prefix="", scales=None):
         o = _ring_attention(q, k_eff, v_eff, abs_pos >= 0)
     else:
         o = decode_attention(q, k_eff, v_eff, pos, scale=cfg.attn_scale)
-    o = o.reshape(B, 1, Hp * hd)
-    out = tp_down_proj(o, lp[f"w{prefix}o"])
+    o = o.reshape(B, 1, cfg.padded_heads * cfg.head_dim)
+    return tp_down_proj(o, lp[f"w{prefix}o"])
+
+
+def _decode_attn_one(cfg, lp, x, kc, vc, pos, prefix="", scales=None):
+    """One-token attention vs. a (B,S,K,hd) cache; returns out, new kc/vc
+    (+ new scales when the cache is int8-quantized).
+
+    ``pos`` is (B,): each lane reads/writes its own cache position
+    (scatter update + per-lane causal mask), which is what lets the
+    service scheduler hold lanes at different chunk offsets. With all
+    lanes equal this computes exactly what the old scalar-pos path did.
+    q and k are rotated unless ``cfg.position_embedding`` is "nope"; the
+    softmax scale is ``cfg.attn_scale`` (default 1/sqrt(head_dim))."""
+    B = x.shape[0]
+    q, k, v = _decode_qkv(cfg, lp, x, pos, prefix)
+    S = kc.shape[1]
+    mesh_ss = _use_seq_sharded_decode(cfg) if not prefix else None
+    if mesh_ss is not None:
+        o, kc, vc, new_scales = _seq_sharded_decode_attn(
+            cfg, mesh_ss, q, k, v, kc, vc, pos, scales=scales)
+        o = o.reshape(B, 1, cfg.padded_heads * cfg.head_dim)
+        out = tp_down_proj(o, lp[f"w{prefix}o"])
+        if scales is not None:
+            return out, kc, vc, new_scales
+        return out, kc, vc
+    slot = _cache_slot(cfg, pos, S)                     # (B,)
+    lanes = jnp.arange(B)
+    leaves = (kc, vc) if scales is None else (kc, vc, *scales)
+    leaves = tuple(c.at[lanes, slot].set(r[:, 0].astype(c.dtype)) for c, r
+                   in zip(leaves, _kv_rows(k, v, scales is not None)))
+    out = _decode_attend(cfg, lp, q, *_kv_read(leaves, x.dtype), pos,
+                         prefix)
     if scales is not None:
-        return out, kc, vc, new_scales
-    return out, kc, vc
+        return out, leaves[0], leaves[1], leaves[2:]
+    return out, leaves[0], leaves[1]
+
+
+def _decode_attn_stacked(cfg, lp, x, kv, layer, pos):
+    """``_decode_attn_one`` for layer ``layer`` of the stacked cache ``kv``
+    = (k, v) or, int8, (k, v, k_scale, v_scale), each (L, B, S, ...).
+    The lanes' new rows are written into the stacked leaves at [layer,
+    lane, slot] and attention reads the layer's slab back out of them, so
+    no slab is copied and a donated cache is updated in place. Same values
+    as ``_decode_attn_one``; returns (out, kv)."""
+    B = x.shape[0]
+    q, k, v = _decode_qkv(cfg, lp, x, pos)
+    slot = _cache_slot(cfg, pos, kv[0].shape[2])        # (B,)
+    lanes = jnp.arange(B)
+    kv = tuple(c.at[layer, lanes, slot].set(r[:, 0].astype(c.dtype))
+               for c, r in zip(kv, _kv_rows(k, v, len(kv) == 4)))
+    k_eff, v_eff = _kv_read([c[layer] for c in kv], x.dtype)
+    return _decode_attend(cfg, lp, q, k_eff, v_eff, pos), kv
 
 
 def _ring_attention(q, kc, vc, valid):
@@ -380,39 +421,43 @@ def _ring_attention(q, kc, vc, valid):
 
 
 def decode_step(params, cfg: ModelConfig, cache, prev_tokens):
-    """One autoregressive step: (cache, prev (B,)) -> (logits (B,Vp), cache)."""
+    """One autoregressive step: (cache, prev (B,)) -> (logits (B,Vp), cache).
+
+    The stacked K/V leaves (and int8 scales) ride in the layer scan's
+    carry, and each layer writes its lanes' new rows into them in place.
+    Under a jit that donates ``cache`` (``ModelPredictor``'s, for the
+    families in ``models.api.IN_PLACE_DECODE``) the returned cache reuses
+    the argument's buffers, and the argument's arrays are consumed."""
     pos = cache["pos"]
     x = embed_tokens(cfg, params, prev_tokens[:, None])
-
-    int8 = cfg.kv_cache_dtype == "int8"
+    names = ("k", "v", "k_scale", "v_scale") \
+        if cfg.kv_cache_dtype == "int8" else ("k", "v")
+    seq_sharded = _use_seq_sharded_decode(cfg) is not None
 
     def body(carry, xs):
-        h = carry
-        if int8:
-            lp, kc, vc, ks, vs = xs
-            a, kc, vc, (ks, vs) = _decode_attn_one(
-                cfg, lp, rms_norm(h, lp["ln1"], cfg.norm_eps), kc, vc, pos,
-                scales=(ks, vs))
+        h, kv = carry
+        lp, layer = xs
+        hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
+        if seq_sharded:
+            # The TP path over a sequence-sharded cache updates a whole
+            # layer slab inside its shard_map, so the slab is sliced out of
+            # the carry and written back. It is lock-step only and the
+            # service refuses it (decode_requires_lockstep).
+            slabs = [c[layer] for c in kv]
+            a, kc, vc, *sc = _decode_attn_one(
+                cfg, lp, hn, slabs[0], slabs[1], pos,
+                scales=tuple(slabs[2:]) or None)
+            kv = tuple(c.at[layer].set(n)
+                       for c, n in zip(kv, (kc, vc, *(sc[0] if sc else ()))))
         else:
-            lp, kc, vc = xs
-            a, kc, vc = _decode_attn_one(
-                cfg, lp, rms_norm(h, lp["ln1"], cfg.norm_eps), kc, vc, pos)
+            a, kv = _decode_attn_stacked(cfg, lp, hn, kv, layer, pos)
         h = h + a
         h = h + swiglu(rms_norm(h, lp["ln2"], cfg.norm_eps),
                        lp["wi_gate"], lp["wi_up"], lp["wo_mlp"])
-        return h, (kc, vc, ks, vs) if int8 else (kc, vc)
+        return (h, kv), None
 
-    if int8:
-        x, (k_new, v_new, ks_new, vs_new) = scan_xs(
-            cfg, body, x, (params["layers"], cache["k"], cache["v"],
-                           cache["k_scale"], cache["v_scale"]))
-    else:
-        x, (k_new, v_new) = scan_xs(
-            cfg, body, x, (params["layers"], cache["k"], cache["v"]))
+    (x, kv), _ = scan_xs(cfg, body, (x, tuple(cache[n] for n in names)),
+                         (params["layers"], jnp.arange(cfg.n_layers)))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = lm_logits(cfg, params, x)[:, 0]
-    new_cache = {"k": k_new, "v": v_new, "pos": pos + 1}
-    if int8:
-        new_cache["k_scale"] = ks_new
-        new_cache["v_scale"] = vs_new
-    return logits, new_cache
+    return logits, {**dict(zip(names, kv)), "pos": pos + 1}

@@ -5,11 +5,16 @@ implements core.compressor.PredictorAdapter over any model-zoo config:
 
   * score_chunks — one jitted teacher-forced forward over (B, C) chunks
     (prefill-shaped; on the production mesh this is the pjit `score_step`).
-  * decode loop — jitted single-token step. The cache is not donated:
-    each step writes a new cache beside the one it read. The step's
-    logits stay on the device: ``decode_step`` returns the program's
-    ``jax.Array``, which the service's CDF program reads in place, and
-    only callers that need host logits copy them.
+  * decode loop — jitted single-token step. For the families that update
+    their cache in place (``model_api.IN_PLACE_DECODE``) the step's cache
+    argument is donated: the new K/V rows land in the buffers it read.
+    ``decode_step`` hands those buffers back into the state it was given,
+    which keeps its position and so still steps as it did, in place of the
+    state returned. The other families' steps write a new cache beside the
+    one they read.
+    The step's logits stay on the device: ``decode_step`` returns the
+    program's ``jax.Array``, which the service's CDF program reads in
+    place, and only callers that need host logits copy them.
 
 The BOS convention: the model input for chunk tokens x_0..x_{C-1} is
 [BOS, x_0, .., x_{C-2}], so logits[t] parameterizes P(x_t | x_<t) with a
@@ -61,6 +66,7 @@ class ModelPredictor:
         stats_kw = {"stats": True} if cfg.family in model_api.STEP_STATS \
             else {}
         self.step_stats = None
+        self._in_place = cfg.family in model_api.IN_PLACE_DECODE
 
         # jax.named_scope labels below mirror the host-span names (minus
         # the dots XProf dislikes) so a captured device trace interleaves
@@ -76,7 +82,7 @@ class ModelPredictor:
                 logits = model_api.forward(params, cfg, batch, **fam_kw)
                 return logits[..., :cfg.vocab_size]
 
-        @jax.jit
+        @partial(jax.jit, donate_argnums=(1,) if self._in_place else ())
         def _decode(params, cache, prev, extra):
             with jax.named_scope("model_decode_step"):
                 logits, cache, *stats = model_api.decode_step(
@@ -276,18 +282,28 @@ class ModelPredictor:
 
     def decode_step(self, state, prev_tokens: np.ndarray):
         """One decode step: (B,) previous tokens -> (B, V) logits and the
-        next state. The logits are the ``jax.Array`` the program writes:
-        nothing waits for it and nothing is copied to the host, so the
-        service hands them straight to its CDF program. A caller that
-        reads them on the host converts them (``np.asarray``). The step's
-        counters, for a family that returns them, stay on the device in
-        ``step_stats`` until the next step."""
+        next state. For a family in ``model_api.IN_PLACE_DECODE`` the step
+        donates ``state``'s K/V buffers and writes its rows into them; they
+        go back into ``state`` with its own ``pos``, so ``state`` still
+        steps exactly as before (a step rewrites each lane's row at ``pos``
+        before it reads it). The two states then share those buffers:
+        step one of them, which consumes both.
+
+        The logits are the ``jax.Array`` the program writes: nothing waits
+        for it and nothing is copied to the host, so the service hands
+        them straight to its CDF program. A caller that reads them on the
+        host converts them (``np.asarray``). The step's counters, for a
+        family that returns them, stay on the device in ``step_stats``
+        until the next step."""
         prev = np.asarray(prev_tokens, np.int32)
         _count_bytes("transfer.h2d_bytes", prev)
-        logits, state, *stats = self._decode(
+        logits, new, *stats = self._decode(
             self.params, state, jnp.asarray(prev), self.extra_batch)
+        if self._in_place:      # state's K/V and pos buffers were donated
+            state.update({k: v for k, v in new.items() if k != "pos"},
+                         pos=new["pos"] - 1)
         self.step_stats = stats[0] if stats else None
-        return logits, state
+        return logits, new
 
     def verify_steps(self, state, seq: np.ndarray):
         """Speculative-decode verify program: score seq (B, T) — column 0

@@ -2,7 +2,7 @@
 float32 reference (``chipbench/reference/granite_hybrid.py``) at a tiny
 size on seeded random weights: prefill and decoding through the mixed
 cache, the expert share of one chip, the service round trip with reused
-slots, and the dense decode program left as it was."""
+slots, and the dense and Granite decode programs pinned by hash."""
 import hashlib
 
 import jax
@@ -164,10 +164,21 @@ def test_service_round_trip_and_reused_slot(model):
     assert 0 < routed < steps * 2 * L * cfg.top_k
 
 
-# The qwen3-1.7b smoke preset's decode program as lowered before the
-# hybrid_moe fields existed (sha256 of ``_decode.lower(...).as_text()``).
+# sha256 of ``_decode.lower(...).as_text()``. Qwen3's: the qwen3-1.7b smoke
+# preset's decode program with its K/V cache carried through the layer
+# scan and donated; a change to the dense decode program must re-pin it on
+# purpose. Granite's: the tiny Granite model's decode program, which the
+# dense in-place cache left as it was (neither restructured nor donated).
 QWEN3_TINY_DECODE_SHA256 = \
-    "451fcd9bb76376feea9c6fe602b22f4378a97f3b5397e4ce1d89ee7bcca2f206"
+    "f7dfa896a50648212f7dbb69e95c0e3e2e372dc2feab57bda69ee0d7bd70d198"
+GRANITE_TINY_DECODE_SHA256 = \
+    "7a6271d9b3a52b5f23d351de800d43befb8b78d3bf725d0bbc9a34f6a7520820"
+
+
+def _decode_sha256(pred, params, cache, batch):
+    text = pred._decode.lower(params, cache, jnp.zeros((batch,), jnp.int32),
+                              {}).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()
 
 
 def test_dense_decode_program_unchanged():
@@ -175,9 +186,14 @@ def test_dense_decode_program_unchanged():
     params = api.init_params(cfg, jax.random.PRNGKey(0))
     pred = ModelPredictor(params, cfg)
     cache = api.init_cache(cfg, 4, 32)
-    text = pred._decode.lower(params, cache, jnp.zeros((4,), jnp.int32),
-                              {}).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == \
-        QWEN3_TINY_DECODE_SHA256
+    assert _decode_sha256(pred, params, cache, 4) == QWEN3_TINY_DECODE_SHA256
     pred.decode_step(cache, np.zeros(4, np.int32))
     assert pred.step_stats is None
+
+
+def test_granite_decode_program_unchanged(model):
+    cfg, _, params = model
+    pred = ModelPredictor(params, cfg)
+    cache = api.init_cache(cfg, 4, 32)
+    assert _decode_sha256(pred, params, cache, 4) == \
+        GRANITE_TINY_DECODE_SHA256

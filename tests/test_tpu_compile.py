@@ -94,6 +94,41 @@ def test_granite_decode_step_fits_one_chip(one_chip):
     assert used < 0.75 * HBM_BYTES, used
 
 
+@pytest.mark.parametrize("cell", ["qwen3-1.7b", "deepseek-7b",
+                                  "granite-4.0-h-small"])
+def test_decode_step_updates_cache_in_place(one_chip, cell):
+    """At the benchmark cells' shapes the dense decode program writes its
+    new K/V rows into the donated cache: the output aliases every cache
+    byte and no cache-sized temporary is made. Granite's program is not
+    donated (its scans pass the cache through their inputs and outputs)."""
+    from repro.models import api as model_api
+    from repro.models.schema import abstract_params
+    from repro.serve.engine import ModelPredictor
+
+    if cell == "qwen3-1.7b":
+        from repro.configs.qwen3_1_7b import CONFIG as cfg
+        B = 64
+    elif cell == "deepseek-7b":
+        from repro.configs.deepseek_7b import CONFIG
+        cfg, B = CONFIG.with_(n_layers=15), 32
+    else:
+        from repro.configs.granite_4_0_h_small import CONFIG
+        cfg, B = CONFIG.with_(n_layers=10, experts_held=9), 64
+    params = _on(one_chip, abstract_params(cfg))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: model_api.init_cache(cfg, B, 256)))
+    prev = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    pred = ModelPredictor(params, cfg)
+    mem = pred._decode.lower(params, cache, prev, {}).compile() \
+        .memory_analysis()
+    if cfg.family not in model_api.IN_PLACE_DECODE:
+        assert mem.alias_size_in_bytes == 0
+        return
+    kv = sum(cache[n].size * cache[n].dtype.itemsize for n in ("k", "v"))
+    assert mem.alias_size_in_bytes >= kv, (mem.alias_size_in_bytes, kv)
+    assert mem.temp_size_in_bytes < 0.01 * kv, mem.temp_size_in_bytes
+
+
 def test_xla_topk_cdf_compiles(one_chip):
     from repro.core.cdf import topk_cdf_jit
     logits = jax.ShapeDtypeStruct((16, V), jnp.float32, sharding=one_chip)
