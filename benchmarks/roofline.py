@@ -1,4 +1,6 @@
-"""Aggregate dry-run cell JSONs into the §Roofline / §Dry-run tables.
+"""Aggregate stored per-cell roofline JSONs (``results/roofline/``:
+arch, shape, roofline terms, bytes per device) into the §Roofline table.
+Nothing in the repo writes those records any more.
 
 With ``--measured FILE`` (a JSON object mapping ``"arch/shape"`` to a
 measured per-step wall time in seconds) the summary additionally emits
@@ -20,7 +22,7 @@ sys.path[:0] = ["src", "."]
 
 from repro.obs import console  # noqa: E402
 
-RESULTS = pathlib.Path(__file__).resolve().parents[1] / "results" / "dryrun"
+RESULTS = pathlib.Path(__file__).resolve().parents[1] / "results" / "roofline"
 
 ARCH_ORDER = ["llava_next_34b", "mamba2_130m", "qwen3_moe_235b_a22b",
               "granite_moe_1b_a400m", "qwen3_14b", "deepseek_7b",

@@ -1,6 +1,6 @@
 """llava-next-34b [vlm] — 60L d_model=7168 56H (GQA kv=8) d_ff=20480
 vocab=64000; anyres tiling [hf:llava-hf/llava-v1.6-mistral-7b-hf; unverified].
-Backbone only; the anyres patch frontend is a stub — input_specs() provides
+Backbone only; the anyres patch frontend is a stub — batches provide
 precomputed patch embeddings (n_img_tokens per image)."""
 from repro.configs.base import ModelConfig, tiny_variant
 

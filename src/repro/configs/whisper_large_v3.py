@@ -1,5 +1,5 @@
 """whisper-large-v3 [audio] — 32L(+32L enc) d_model=1280 20H (MHA kv=20)
-d_ff=5120 vocab=51866 — enc-dec, conv frontend STUB (input_specs provides
+d_ff=5120 vocab=51866 — enc-dec, conv frontend STUB (batches provide
 precomputed frame embeddings) [arXiv:2212.04356; unverified].
 20 heads pad to 32 for TP=16; decoder self-attn uses RoPE (adaptation from
 learned positions so the assigned 32k decode shape is well-defined)."""

@@ -181,59 +181,6 @@ def full_cdf_lookup(logits: jnp.ndarray, slots: jnp.ndarray,
 full_cdf_lookup_jit = jax.jit(full_cdf_lookup, static_argnums=(2,))
 
 
-def topk_quantized_sharded(logits, k: int, precision: int, mesh,
-                           batch_axes=("data",)):
-    """Hierarchical top-K + escape quantization for VOCAB-SHARDED logits.
-
-    Plain lax.top_k over a sharded dim makes the SPMD partitioner
-    all-gather the full fp32 logits (measured 38 GiB + 608 GiB per
-    1-layer prefill probe on qwen3-1.7b!). Instead, inside shard_map:
-    each vocab shard computes its local top-k, the tp*k candidates
-    (not V) are all-gathered, and the softmax denominator is a psum of
-    local sum-exps. Collective bytes per token drop from O(V) to O(tp*k).
-
-    logits (..., V) sharded (batch_axes..., None, 'model').
-    Returns (ids, qpmf) replicated over 'model'.
-    """
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-    tp = mesh.shape["model"]
-    V = logits.shape[-1]
-    assert V % tp == 0
-    v_loc = V // tp
-
-    def mapped(lg):
-        lg = lg.astype(jnp.float32)
-        lmax = jnp.max(lg, axis=-1, keepdims=True)
-        gmax = jax.lax.pmax(lmax, "model")
-        denom = jax.lax.psum(
-            jnp.sum(jnp.exp(lg - gmax), axis=-1, keepdims=True), "model")
-        vals, idx = jax.lax.top_k(lg, k)
-        idx = idx + jax.lax.axis_index("model") * v_loc
-        cand_v = jax.lax.all_gather(vals, "model", axis=-1, tiled=True)
-        cand_i = jax.lax.all_gather(idx, "model", axis=-1, tiled=True)
-        vals2, pos = jax.lax.top_k(cand_v, k)
-        ids = jnp.take_along_axis(cand_i, pos, axis=-1)
-        top_p = jnp.exp(vals2 - gmax) / denom
-        escape_p = jnp.clip(1.0 - jnp.sum(top_p, axis=-1, keepdims=True),
-                            0.0, 1.0)
-        pmf = jnp.concatenate([top_p, escape_p], axis=-1)
-        pmf = pmf / jnp.sum(pmf, axis=-1, keepdims=True)
-        return ids.astype(jnp.int32), quantize_pmf(pmf, precision)
-
-    # batch axes on dim 0, None in between, 'model' on the vocab dim
-    nd = logits.ndim
-    dims = [None] * nd
-    dims[0] = tuple(batch_axes) if batch_axes else None
-    dims[-1] = "model"
-    in_spec = P(*dims)
-    out_dims = list(dims)
-    out_dims[-1] = None
-    out_spec = P(*out_dims)
-    return shard_map(mapped, mesh=mesh, in_specs=in_spec,
-                     out_specs=(out_spec, out_spec), check_rep=False)(logits)
-
-
 def build_topk_cdfs(ids: np.ndarray, qpmf: np.ndarray):
     """Host-side: (ids, qpmf) -> per-position (ids, cdf) pairs."""
     return np.asarray(ids), pmf_to_cdf(np.asarray(qpmf))

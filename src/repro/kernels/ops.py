@@ -9,8 +9,8 @@
 * ``"auto"``      — ``"pallas"`` on a TPU backend, ``"ref"`` elsewhere.
 
 The model zoo's XLA paths (models/layers.py) implement the same
-algorithms, so the dry-run HLO is structurally faithful to what the
-kernels do on TPU.
+algorithms, so their HLO is structurally faithful to what the kernels do
+on TPU.
 """
 from __future__ import annotations
 

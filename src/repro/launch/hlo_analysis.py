@@ -1,5 +1,4 @@
-"""Collective-traffic + roofline-term extraction from compiled dry-run
-artifacts.
+"""Collective-traffic + roofline-term extraction from compiled HLO.
 
 collective_bytes is not in cost_analysis(): we parse the optimized HLO
 text and sum the OUTPUT shape bytes of every all-gather / all-reduce /
@@ -46,7 +45,7 @@ def collective_stats(hlo_text: str, *, wire_correction: bool = False) -> dict:
     `-start` ops are counted once (`-done` carries no shape of its own
     in the tuple form, so only count starts and plain ops).
 
-    wire_correction: the CPU dry-run backend PROMOTES bf16 all-reduces to
+    wire_correction: the CPU backend PROMOTES bf16 all-reduces to
     f32 (bf16 reductions unsupported on host) — 2x the bytes a TPU
     lowering moves. Our explicit shard_map psums keep their jax op name
     ('%psum*'); with correction on, f32 all-reduces named psum are counted
@@ -159,8 +158,8 @@ def analytic_memory_bytes(cfg, shape, *, n_chips: int, tp: int,
                 dtype_b / n_chips
     else:
         eff_S = min(S, cfg.sliding_window or S)
-        # cache_pspecs shards over BOTH axes: batch (or seq) -> data,
-        # kv-heads (or seq) -> model  =>  divisor = n_chips
+        # the cache is taken as sharded over BOTH axes: batch (or seq) ->
+        # data, kv-heads (or seq) -> model  =>  divisor = n_chips
         kv_b = 1 if getattr(cfg, "kv_cache_dtype", None) == "int8" else dtype_b
         cache = L * B * eff_S * kv_heads * cfg.head_dim * 2 * kv_b / n_chips
         if kv_b == 1:  # int8 scales (fp16 per position/head)

@@ -1,8 +1,8 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state (the dry-run sets XLA_FLAGS before any jax import; tests see the
-single real device).
+state (a process that wants host devices sets XLA_FLAGS before its first
+jax import; tests see the single real device).
 
 Axes:
   pod   — DCN-connected pods; data-parallel only (gradient all-reduce).
@@ -20,12 +20,6 @@ def _auto_mesh(shape, axes):
     # jax.make_mesh defaults to Explicit axis types; models/layers.shard()
     # emits with_sharding_constraint, which only accepts Auto axes.
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape, axes=None):

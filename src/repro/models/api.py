@@ -2,8 +2,6 @@
 model families."""
 from __future__ import annotations
 
-from typing import Any
-
 import jax
 import jax.numpy as jnp
 
@@ -48,9 +46,6 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None, **kw):
 
 def decode_step(params, cfg: ModelConfig, cache, prev_tokens, **kw):
     """(logits (B, padded_vocab), new_cache)."""
-    if cfg.family in ("moe",):
-        return moe.decode_step(params, cfg, cache, prev_tokens, **kw)
-    kw.pop("mesh", None)
     return module_for(cfg).decode_step(params, cfg, cache, prev_tokens, **kw)
 
 

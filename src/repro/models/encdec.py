@@ -1,7 +1,7 @@
 """Whisper-style encoder-decoder backbone.
 
-The audio conv frontend is a STUB per the assignment: `input_specs()` /
-batches provide precomputed frame embeddings (B, S_enc, D) directly.
+The audio conv frontend is a STUB per the assignment: batches provide
+precomputed frame embeddings (B, S_enc, D) directly.
 Encoder: bidirectional attention + sinusoidal positions. Decoder: causal
 self-attention (RoPE — adaptation from whisper's learned embeddings so the
 assigned 32k decode shapes are well-defined; recorded in DESIGN.md) +

@@ -17,7 +17,6 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -27,21 +26,18 @@ NEG_INF = -1e30
 
 # Trace-time mesh context: jit tracing does not expose the target mesh
 # (jax.sharding.get_abstract_mesh() is empty unless set_mesh is active),
-# so the step builders wrap their bodies in mesh_context(mesh) and shard()
+# so the train step wraps its body in mesh_context(mesh) and shard()
 # reads it to emit constraints with only the axes that exist.
 _MESH_VAR = contextvars.ContextVar("repro_mesh", default=None)
-_LAYOUT_VAR = contextvars.ContextVar("repro_layout", default="train")
 
 
 @contextlib.contextmanager
-def mesh_context(mesh, layout: str = "train"):
+def mesh_context(mesh):
     tok = _MESH_VAR.set(mesh)
-    tok2 = _LAYOUT_VAR.set(layout)
     try:
         yield
     finally:
         _MESH_VAR.reset(tok)
-        _LAYOUT_VAR.reset(tok2)
 
 
 def shard(x, *axes):
@@ -49,7 +45,7 @@ def shard(x, *axes):
     the mesh are dropped (e.g. 'pod' on the single-pod mesh) — naming a
     missing axis raises inside jit and a skipped constraint measurably
     de-shards activations (batch replicated across 'data' in the backward;
-    found via 16x-inflated collective bytes in the dry-run)."""
+    found via 16x-inflated collective bytes in the compiled train step)."""
     mesh = _MESH_VAR.get()
     if mesh is None:
         return x
@@ -64,27 +60,6 @@ def shard(x, *axes):
     spec = P(*(filt(a) for a in axes))
     return jax.lax.with_sharding_constraint(
         x, jax.sharding.NamedSharding(mesh, spec))
-
-
-import os
-
-BF16_WIRE = os.environ.get("REPRO_BF16_WIRE", "0") == "1"
-EXPLICIT_TP = os.environ.get("REPRO_EXPLICIT_TP", "0") == "1"
-# EXPLICIT_TP: lower the TP down-projections (attention out, MLP down) with
-# an explicit shard_map (FSDP gather + local matmul + **bf16** psum). The
-# implicit-pjit path all-reduces the dot output, which on the CPU dry-run
-# backend is fp32 (bf16 dots lower to fp32) — 2x the wire bytes a TPU
-# lowering would move. Explicit collectives make the wire dtype a design
-# decision instead of a backend artifact. §Perf iteration I5.
-# When set, a barrier after each residual add stops XLA from hoisting the
-# rms_norm fp32 upcast above the TP all-reduce — activations cross the
-# wire in bf16 (2x fewer collective bytes). §Perf iteration I5.
-
-
-def residual_barrier(x):
-    if BF16_WIRE:
-        return jax.lax.optimization_barrier(x)
-    return x
 
 
 def scale_residual(cfg, y):
@@ -115,46 +90,11 @@ def rope(x, positions, theta: float = 1e6):
     return out.astype(x.dtype)
 
 
-def tp_down_proj(h, w, *, fsdp_axes=("embed",)):
-    """Down-projection contracting a TP-sharded inner dim.
-    h (B,S,F) sharded (batch, None, 'model'); w (F, D) sharded
-    ('model', 'data'). With EXPLICIT_TP and an active mesh: shard_map with
-    FSDP weight gather + local matmul + bf16 psum; otherwise plain einsum
-    (pjit inserts the all-reduce)."""
-    mesh = _MESH_VAR.get()
-    if not EXPLICIT_TP or mesh is None or "model" not in mesh.axis_names             or mesh.shape["model"] == 1:
-        return residual_barrier(jnp.einsum("bsf,fd->bsd", h, w))
-    from jax.experimental.shard_map import shard_map
-    names = set(mesh.axis_names)
-    ba = tuple(a for a in ("pod", "data") if a in names)
-    nb = 1
-    for a in ba:
-        nb *= mesh.shape[a]
-    bspec = ba if ba and h.shape[0] % nb == 0 else None
-    dp = mesh.shape.get("data", 1)
-    w_fsdp = ("data" in names and dp > 1 and w.shape[1] % dp == 0
-              and _LAYOUT_VAR.get() == "train")
-
-    def mapped(h_loc, w_loc):
-        if w_fsdp:
-            w_loc = jax.lax.all_gather(w_loc, "data", axis=1, tiled=True)
-        out = jnp.einsum("bsf,fd->bsd", h_loc, w_loc)
-        # wire dtype = model dtype (bf16 in production): the psum payload is
-        # an explicit design choice, not a backend lowering artifact
-        return jax.lax.psum(out.astype(h.dtype), "model")
-
-    return shard_map(
-        mapped, mesh=mesh,
-        in_specs=(P(bspec, None, "model"),
-                  P("model", "data" if w_fsdp else None)),
-        out_specs=P(bspec, None, None), check_rep=False)(h, w)
-
-
 def swiglu(x, w_gate, w_up, w_down, *, tp_axis="model"):
     h = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, w_gate)) * \
         jnp.einsum("bsd,df->bsf", x, w_up)
     h = shard(h, ("pod", "data"), None, tp_axis)
-    return tp_down_proj(h, w_down)
+    return jnp.einsum("bsf,fd->bsd", h, w_down)
 
 
 # --------------------------------------------------------------------- attn
@@ -297,10 +237,9 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=None, scale=None):
 
 def attention_dense(q, k, v, *, causal=True, window=None,
                     q_offset=0, k_offset=0, q_chunk=None, scale=None):
-    """Loop-free masked attention (single einsum chain). Used by the
-    dry-run COST PROBES: XLA's HloCostAnalysis counts while-loop bodies
-    once, so probes must not contain loops. Memory-naive (materializes
-    S x S scores) — never used on a real workload path."""
+    """Loop-free masked attention (single einsum chain), the oracle the
+    chunked implementations are tested against. Memory-naive
+    (materializes S x S scores) — never used on a real workload path."""
     B, Sq, H, hd = q.shape
     _, Sk, K, _ = k.shape
     G = H // K

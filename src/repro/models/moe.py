@@ -23,13 +23,12 @@ smoke tests and as the oracle for the distributed test.
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from .layers import rms_norm, shard
+from .layers import rms_norm
 
 
 def _route(x_flat, router_w, top_k):
@@ -131,66 +130,8 @@ def moe_block(cfg: ModelConfig, lp: dict, x, *, mesh=None, dropless=False,
 
     from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    from .layers import _LAYOUT_VAR
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     tp = mesh.shape["model"]
-    dp = mesh.shape.get("data", 1)
-    serve = (_LAYOUT_VAR.get() == "serve" and dp > 1
-             and cfg.d_model % dp == 0)
-
-    if serve:
-        # Serve layout: tokens are few (decode) — REPLICATE them over
-        # 'data' and contract each chip's resident D-slice of its local
-        # experts; psum partials over ('data','model'). No weight gather.
-        def mapped_serve(xf, router, wg, wu, wd):
-            shard_m = jax.lax.axis_index("model")
-            shard_d = jax.lax.axis_index("data")
-            D_loc = cfg.d_model // dp
-            lp_loc = {"router": router}
-            E, k = cfg.n_experts, cfg.top_k
-            E_loc = E // tp
-            T = xf.shape[0]
-            C = T  # dropless
-            weights, experts = _route(xf, router, k)
-            fe = experts.reshape(-1)
-            rank = _rank_within_expert(fe, E)
-            local = (fe >= shard_m * E_loc) & (fe < (shard_m + 1) * E_loc)
-            keep = (rank < C) & local
-            le = fe - shard_m * E_loc
-            dest = jnp.where(keep, le * C + rank, E_loc * C)
-            tok_idx = jnp.repeat(jnp.arange(T), k)
-            x_slice = jax.lax.dynamic_slice(
-                xf, (0, shard_d * D_loc), (T, D_loc))
-            buf = jnp.zeros((E_loc * C + 1, D_loc), xf.dtype)
-            buf = buf.at[dest].set(x_slice[tok_idx], mode="drop")
-            bufe = buf[:-1].reshape(E_loc, C, D_loc)
-            # D-partial up/gate, psum over data, then local down D-slice
-            hg = jnp.einsum("ecd,edf->ecf", bufe, wg)
-            hu = jnp.einsum("ecd,edf->ecf", bufe, wu)
-            hg = jax.lax.psum(hg, "data")
-            hu = jax.lax.psum(hu, "data")
-            h = jax.nn.silu(hg) * hu
-            out = jnp.einsum("ecf,efd->ecd", h, wd)   # (E_loc, C, D_loc)
-            out = jnp.concatenate(
-                [out.reshape(E_loc * C, D_loc),
-                 jnp.zeros((1, D_loc), out.dtype)], 0)
-            y_slots = out[dest] * (weights.reshape(-1)[:, None] *
-                                   keep[:, None]).astype(out.dtype)
-            y = jnp.sum(y_slots.reshape(T, k, D_loc), axis=1)
-            # assemble full D by all-gather over data (tiny: T x D_loc),
-            # sum expert contributions over model
-            y = jax.lax.all_gather(y, "data", axis=1, tiled=True)
-            return jax.lax.psum(y, "model")
-
-        y = shard_map(
-            mapped_serve, mesh=mesh,
-            in_specs=(P(None, None), P(None, None),
-                      P("model", "data", None), P("model", "data", None),
-                      P("model", None, "data")),
-            out_specs=P(None, None),
-            check_rep=False,
-        )(x_flat, lp["router"], lp["we_gate"], lp["we_up"], lp["we_down"])
-        return y.reshape(B, S, D)
 
     def mapped(xf, router, wg, wu, wd):
         # FSDP gather of expert weights over 'data' (D rows axis=2 of (E,D,F))
@@ -265,7 +206,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None):
     return dense_init_cache(cfg, batch, max_len, dtype)
 
 
-def decode_step(params, cfg: ModelConfig, cache, prev_tokens, *, mesh=None,
+def decode_step(params, cfg: ModelConfig, cache, prev_tokens, *,
                 dropless=True):
     from .transformer import (_decode_attn_one, embed_tokens, lm_logits)
     pos = cache["pos"]
@@ -278,7 +219,7 @@ def decode_step(params, cfg: ModelConfig, cache, prev_tokens, *, mesh=None,
                                      kc, vc, pos)
         h = h + a
         h = h + moe_block(cfg, lp, rms_norm(h, lp["ln2"], cfg.norm_eps),
-                          mesh=mesh, dropless=dropless)
+                          dropless=dropless)
         return h, (kc, vc)
 
     from .transformer import scan_xs

@@ -3,7 +3,7 @@
 Every model family declares its parameters as a nested dict of ``Leaf``
 entries (shape, logical axes, init). From the schema we derive:
   * ``init_params``  — real arrays (smoke tests, measured benchmarks)
-  * ``abstract_params`` — ShapeDtypeStructs (dry-run; no allocation)
+  * ``abstract_params`` — ShapeDtypeStructs (no allocation)
   * ``param_axes``   — logical-axis tree consumed by sharding/specs.py
 
 Logical axis names (mapped to mesh axes in sharding/specs.py):
@@ -74,8 +74,6 @@ def _moe_leaves(cfg: ModelConfig, L: int) -> dict:
     s_in, s_out = 1.0 / np.sqrt(D), 1.0 / np.sqrt(F)
     # Experts take the TP ('model') axis => per-expert F stays unsharded;
     # D rows keep the FSDP ('embed' -> data) axis.
-    # 'expert_embed': expert D rows keep the 2D sharding even in the serve
-    # layout (resident experts would not fit HBM) — see sharding/specs.py.
     return {
         "router": Leaf((L, D, E), ("layers", "embed", None), "normal", s_in),
         "we_gate": Leaf((L, Eh, D, F), ("layers", "expert", "expert_embed", None), "normal", s_in),

@@ -10,11 +10,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from .layers import (ATTN_IMPLS, decode_attention, residual_barrier,
-                     rms_norm, rope, shard, swiglu, tp_down_proj, NEG_INF)
+from .layers import (ATTN_IMPLS, decode_attention, rms_norm, rope, shard,
+                     swiglu, NEG_INF)
 
 
 # ------------------------------------------------------------ shared blocks
@@ -47,7 +46,7 @@ def attn_block(cfg: ModelConfig, lp: dict, x, *, positions,
     o = impl(q, k, v, causal=causal, window=cfg.sliding_window, q_chunk=q_chunk,
              scale=cfg.attn_scale)
     o = o.reshape(B, S, Hp * hd)
-    return tp_down_proj(o, lp[f"w{prefix}o"]), (k, v)
+    return jnp.einsum("bsf,fd->bsd", o, lp[f"w{prefix}o"]), (k, v)
 
 
 def dense_block(cfg: ModelConfig, lp: dict, x, *, positions,
@@ -55,11 +54,9 @@ def dense_block(cfg: ModelConfig, lp: dict, x, *, positions,
     a, _ = attn_block(cfg, lp, rms_norm(x, lp["ln1"], cfg.norm_eps),
                       positions=positions, attn_impl=attn_impl,
                       q_chunk=q_chunk, causal=causal)
-    x = residual_barrier(x + a)
-    x = residual_barrier(
-        x + swiglu(rms_norm(x, lp["ln2"], cfg.norm_eps),
-                   lp["wi_gate"], lp["wi_up"], lp["wo_mlp"]))
-    return x
+    x = x + a
+    return x + swiglu(rms_norm(x, lp["ln2"], cfg.norm_eps),
+                      lp["wi_gate"], lp["wi_up"], lp["wo_mlp"])
 
 
 def embed_tokens(cfg: ModelConfig, params, tokens):
@@ -78,8 +75,8 @@ def lm_logits(cfg: ModelConfig, params, x):
 
 
 def scan_xs(cfg: ModelConfig, body, carry, xs):
-    """lax.scan when cfg.scan_layers else an unrolled Python loop (cost
-    probes need loop-free HLO — see launch/dryrun.py)."""
+    """lax.scan when cfg.scan_layers else an unrolled Python loop
+    (loop-free HLO, which XLA's cost analysis counts in full)."""
     if cfg.scan_layers:
         return jax.lax.scan(body, carry, xs)
     L = jax.tree_util.tree_leaves(xs)[0].shape[0]
@@ -184,123 +181,6 @@ def _cache_slot(cfg: ModelConfig, pos, cache_len):
     return pos % cache_len if cfg.sliding_window else pos
 
 
-def decode_requires_lockstep(cfg, mesh=None) -> bool:
-    """True when decode for ``cfg`` takes the seq-sharded TP attention
-    path (KV heads don't divide TP, no sliding window, explicit-TP or
-    serve layout): that path collapses per-lane cache positions to a
-    single max, so it is lock-step only — no per-slot refill. ``mesh``
-    defaults to the ambient mesh context; callers outside the context
-    (the service scheduler's up-front refusal) pass the predictor's mesh
-    explicitly. One predicate shared with ``_use_seq_sharded_decode`` so
-    the refusal and the dispatch cannot drift."""
-    from .layers import _MESH_VAR, _LAYOUT_VAR, EXPLICIT_TP
-    mesh = _MESH_VAR.get() if mesh is None else mesh
-    explicit = EXPLICIT_TP or _LAYOUT_VAR.get() == "serve"
-    if not explicit or mesh is None \
-            or "model" not in getattr(mesh, "axis_names", ()):
-        return False
-    tp = mesh.shape["model"]
-    return (tp > 1 and getattr(cfg, "padded_kv_heads", 0) % tp != 0
-            and not getattr(cfg, "sliding_window", 0))
-
-
-def _use_seq_sharded_decode(cfg):
-    """Flash-decode combine applies when the cache seq dim is TP-sharded
-    (KV heads don't divide TP) — see cache_pspecs."""
-    from .layers import _MESH_VAR
-    mesh = _MESH_VAR.get()
-    return mesh if decode_requires_lockstep(cfg, mesh) else None
-
-
-def _seq_sharded_decode_attn(cfg, mesh, q, k_new, v_new, kc, vc, pos,
-                             scales=None):
-    """Flash-decode over a SEQUENCE-sharded KV cache (KV heads don't divide
-    TP, e.g. kv=8 on model=16). shard_map: each model shard updates its
-    local slice, computes a partial online softmax, and partials combine
-    with a log-sum-exp psum — O(B·H·hd) wire bytes instead of XLA's
-    cache-sized gather (§Perf iteration C2). Returns (o, kc, vc, scales).
-
-    This TP path keeps the lock-step assumption: all lanes share one
-    position (the service scheduler's per-slot reset is a single-host /
-    replicated-cache feature; see DESIGN.md §8)."""
-    from jax.experimental.shard_map import shard_map
-    pos = jnp.max(jnp.asarray(pos))     # uniform across lanes by contract
-    B, _, Hp, hd = q.shape
-    S = kc.shape[1]
-    tp = mesh.shape["model"]
-    S_loc = S // tp
-    names = set(mesh.axis_names)
-    ba = tuple(a for a in ("pod", "data") if a in names)
-    nb = 1
-    for a in ba:
-        nb *= mesh.shape[a]
-    bspec = ba if ba and B % nb == 0 else None
-    int8 = scales is not None
-
-    def mapped(q, k_new, v_new, kc_loc, vc_loc, *sc):
-        shard = jax.lax.axis_index("model")
-        in_mine = (pos >= shard * S_loc) & (pos < (shard + 1) * S_loc)
-        slot_loc = jnp.where(in_mine, pos - shard * S_loc, 0)
-
-        def upd4(c, n):
-            return jnp.where(in_mine, jax.lax.dynamic_update_slice(
-                c, n.astype(c.dtype), (0, slot_loc, 0, 0)), c)
-
-        def upd3(c, n):
-            return jnp.where(in_mine, jax.lax.dynamic_update_slice(
-                c, n.astype(c.dtype), (0, slot_loc, 0)), c)
-
-        if int8:
-            ks_loc, vs_loc = sc
-            kq, k_sc = _quant_kv(k_new)
-            vq, v_sc = _quant_kv(v_new)
-            kc_loc, vc_loc = upd4(kc_loc, kq), upd4(vc_loc, vq)
-            ks_loc, vs_loc = upd3(ks_loc, k_sc), upd3(vs_loc, v_sc)
-            k_eff = _dequant_kv(kc_loc, ks_loc)
-            v_eff = _dequant_kv(vc_loc, vs_loc)
-        else:
-            kc_loc, vc_loc = upd4(kc_loc, k_new), upd4(vc_loc, v_new)
-            k_eff, v_eff = kc_loc, vc_loc
-        K = k_eff.shape[2]
-        G = Hp // K
-        qg = q[:, 0].reshape(-1, K, G, hd)
-        s = jnp.einsum("bkgh,bskh->bkgs", qg.astype(jnp.float32),
-                       k_eff.astype(jnp.float32)) / jnp.sqrt(float(hd))
-        idx = shard * S_loc + jnp.arange(S_loc)
-        s = jnp.where((idx <= pos)[None, None, None, :], s, NEG_INF)
-        m_loc = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m_loc)
-        l_loc = jnp.sum(p, axis=-1, keepdims=True)
-        o_loc = jnp.einsum("bkgs,bskh->bkgh", p, v_eff.astype(jnp.float32))
-        m_g = jax.lax.pmax(m_loc, "model")
-        w = jnp.exp(m_loc - m_g)                 # (b,K,G,1)
-        l = jax.lax.psum(l_loc * w, "model")     # (b,K,G,1)
-        o = jax.lax.psum(o_loc * w, "model")     # (b,K,G,hd)
-        o = o / jnp.maximum(l, 1e-30)
-        out = o.reshape(-1, 1, Hp, hd).astype(q.dtype)
-        if int8:
-            return out, kc_loc, vc_loc, ks_loc, vs_loc
-        return out, kc_loc, vc_loc
-
-    kv_spec = P(bspec, "model", None, None)
-    sc_spec = P(bspec, "model", None)
-    q_spec = P(bspec, None, None, None)
-    if int8:
-        o, kc, vc, ks, vs = shard_map(
-            mapped, mesh=mesh,
-            in_specs=(q_spec, q_spec, q_spec, kv_spec, kv_spec,
-                      sc_spec, sc_spec),
-            out_specs=(q_spec, kv_spec, kv_spec, sc_spec, sc_spec),
-            check_rep=False)(q, k_new, v_new, kc, vc, *scales)
-        return o, kc, vc, (ks, vs)
-    o, kc, vc = shard_map(
-        mapped, mesh=mesh,
-        in_specs=(q_spec, q_spec, q_spec, kv_spec, kv_spec),
-        out_specs=(q_spec, kv_spec, kv_spec),
-        check_rep=False)(q, k_new, v_new, kc, vc)
-    return o, kc, vc, None
-
-
 def _decode_qkv(cfg, lp, x, pos, prefix=""):
     """One token's q (B,1,Hp,hd) and k, v (B,1,Kp,hd): projected, qk-normed
     when the config says so, and rotated at each lane's ``pos`` unless
@@ -352,7 +232,7 @@ def _decode_attend(cfg, lp, q, k_eff, v_eff, pos, prefix=""):
     else:
         o = decode_attention(q, k_eff, v_eff, pos, scale=cfg.attn_scale)
     o = o.reshape(B, 1, cfg.padded_heads * cfg.head_dim)
-    return tp_down_proj(o, lp[f"w{prefix}o"])
+    return jnp.einsum("bsf,fd->bsd", o, lp[f"w{prefix}o"])
 
 
 def _decode_attn_one(cfg, lp, x, kc, vc, pos, prefix="", scales=None):
@@ -367,17 +247,7 @@ def _decode_attn_one(cfg, lp, x, kc, vc, pos, prefix="", scales=None):
     softmax scale is ``cfg.attn_scale`` (default 1/sqrt(head_dim))."""
     B = x.shape[0]
     q, k, v = _decode_qkv(cfg, lp, x, pos, prefix)
-    S = kc.shape[1]
-    mesh_ss = _use_seq_sharded_decode(cfg) if not prefix else None
-    if mesh_ss is not None:
-        o, kc, vc, new_scales = _seq_sharded_decode_attn(
-            cfg, mesh_ss, q, k, v, kc, vc, pos, scales=scales)
-        o = o.reshape(B, 1, cfg.padded_heads * cfg.head_dim)
-        out = tp_down_proj(o, lp[f"w{prefix}o"])
-        if scales is not None:
-            return out, kc, vc, new_scales
-        return out, kc, vc
-    slot = _cache_slot(cfg, pos, S)                     # (B,)
+    slot = _cache_slot(cfg, pos, kc.shape[1])           # (B,)
     lanes = jnp.arange(B)
     leaves = (kc, vc) if scales is None else (kc, vc, *scales)
     leaves = tuple(c.at[lanes, slot].set(r[:, 0].astype(c.dtype)) for c, r
@@ -432,25 +302,12 @@ def decode_step(params, cfg: ModelConfig, cache, prev_tokens):
     x = embed_tokens(cfg, params, prev_tokens[:, None])
     names = ("k", "v", "k_scale", "v_scale") \
         if cfg.kv_cache_dtype == "int8" else ("k", "v")
-    seq_sharded = _use_seq_sharded_decode(cfg) is not None
 
     def body(carry, xs):
         h, kv = carry
         lp, layer = xs
-        hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
-        if seq_sharded:
-            # The TP path over a sequence-sharded cache updates a whole
-            # layer slab inside its shard_map, so the slab is sliced out of
-            # the carry and written back. It is lock-step only and the
-            # service refuses it (decode_requires_lockstep).
-            slabs = [c[layer] for c in kv]
-            a, kc, vc, *sc = _decode_attn_one(
-                cfg, lp, hn, slabs[0], slabs[1], pos,
-                scales=tuple(slabs[2:]) or None)
-            kv = tuple(c.at[layer].set(n)
-                       for c, n in zip(kv, (kc, vc, *(sc[0] if sc else ()))))
-        else:
-            a, kv = _decode_attn_stacked(cfg, lp, hn, kv, layer, pos)
+        a, kv = _decode_attn_stacked(
+            cfg, lp, rms_norm(h, lp["ln1"], cfg.norm_eps), kv, layer, pos)
         h = h + a
         h = h + swiglu(rms_norm(h, lp["ln2"], cfg.norm_eps),
                        lp["wi_gate"], lp["wi_up"], lp["wo_mlp"])

@@ -14,7 +14,7 @@ greppable in text.
 ``exception_record(exc)`` is the structured replacement for
 ``traceback.format_exc()`` string concatenation: a JSON-serializable
 dict with the exception type, message, and frame list, suitable for
-error sidecar files (see launch/dryrun.py).
+error sidecar files.
 
 The repo lint (tools/lint_no_print.py, wired into CI) forbids bare
 ``print(`` anywhere in src/repro outside cli.py — operational output

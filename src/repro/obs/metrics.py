@@ -13,7 +13,7 @@ Design (DESIGN.md §10)
   scheduler step is one boolean attribute check (~0; gated in CI by
   ``benchmarks/run.py telemetry_overhead``).
 * **Process-global default + injectable instances.** Module-level code
-  (spans, structured logs, dryrun error counters) records into
+  (spans, structured logs and their error counters) records into
   ``obs.registry()``; components that need isolation (a
   ``CompressionService`` whose ``stats()`` must describe *its own*
   traffic) construct or accept their own ``MetricsRegistry``. Inject

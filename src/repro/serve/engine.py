@@ -4,7 +4,7 @@ This is the inference side of the paper's system. `ModelPredictor`
 implements core.compressor.PredictorAdapter over any model-zoo config:
 
   * score_chunks — one jitted teacher-forced forward over (B, C) chunks
-    (prefill-shaped; on the production mesh this is the pjit `score_step`).
+    (prefill-shaped).
   * decode loop — jitted single-token step. For the families that update
     their cache in place (``model_api.IN_PLACE_DECODE``) the step's cache
     argument is donated: the new K/V rows land in the buffers it read.
@@ -52,17 +52,14 @@ class ModelPredictor:
     """PredictorAdapter over the model zoo (single-host execution)."""
 
     def __init__(self, params, cfg: ModelConfig, *, bos_id: int | None = None,
-                 extra_batch: dict | None = None, mesh=None):
+                 extra_batch: dict | None = None):
         self.params = params
         self.cfg = cfg
         self.vocab_size = cfg.vocab_size
         self.bos_id = bos_id if bos_id is not None else cfg.vocab_size - 1
         self.extra_batch = extra_batch or {}
-        self.mesh = mesh
         fam_kw = {"dropless": True} if cfg.family in model_api.DROPLESS \
             else {}
-        if cfg.family == "moe" and mesh is not None:
-            fam_kw["mesh"] = mesh
         stats_kw = {"stats": True} if cfg.family in model_api.STEP_STATS \
             else {}
         self.step_stats = None

@@ -171,21 +171,6 @@ class SlotScheduler:
         if not 0 < precision <= rans.MAX_PRECISION:
             raise ValueError(f"precision {precision} outside rANS range "
                              f"(1..{rans.MAX_PRECISION})")
-        # The seq-sharded TP decode path collapses per-lane cache positions
-        # with jnp.max — lock-step only; running it under slot refill would
-        # corrupt streams silently. Refuse up front (same predicate the
-        # model's decode dispatch uses, so the two cannot drift); such
-        # predictors must use the grouped decoder.
-        cfg = getattr(predictor, "cfg", None)
-        if cfg is not None:
-            from repro.models.transformer import decode_requires_lockstep
-            if decode_requires_lockstep(cfg, getattr(predictor, "mesh",
-                                                     None)):
-                raise ValueError(
-                    "continuous batching needs per-lane cache positions; "
-                    "the seq-sharded TP decode path (padded_kv_heads not "
-                    "divisible by TP) is lock-step only — use a replicated-"
-                    "cache predictor or LLMCompressor's grouped decoder")
         self.predictor = predictor
         self.B = int(n_slots)
         self.C = int(chunk_size)

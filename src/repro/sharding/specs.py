@@ -1,37 +1,29 @@
 """Logical axis -> mesh axis mapping (the sharding policy).
 
-One place decides how every parameter / activation / cache tensor is laid
-out on the (pod, data, model) mesh; see DESIGN.md §4 for the table and the
-divisibility fallbacks (non-divisible KV heads -> sequence-sharded caches,
-small SSM head counts -> replicated inner dim, etc.).
+One place decides how every parameter and input batch of the train step
+is laid out on the (pod, data, model) mesh; see DESIGN.md §4 for the table
+and the divisibility fallbacks (an axis that does not divide its mesh
+degree is replicated).
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.launch.mesh import batch_axes, mesh_degree
 from repro.models.schema import param_axes
 
 
-def _rules(cfg: ModelConfig, mesh, layout: str = "train") -> dict:
-    """layout="train": FSDP (D rows over 'data') + TP — optimizer states
-    shard with the params. layout="serve": weights RESIDENT, 1D TP only —
-    FSDP would all-gather the full weight slice every decode step (measured
-    1.85 GB/chip/step on qwen3-14b decode_32k) and serving has no optimizer
-    states to amortize it. MoE expert weights keep D->'data' (2D: resident
-    would not fit) — the serve MoE path psums D-partials instead of
-    gathering (models/moe.py)."""
+def _rules(cfg: ModelConfig, mesh) -> dict:
+    """FSDP (D rows over 'data') + TP — optimizer states shard with the
+    params."""
     tp = mesh_degree(mesh, "model")
     dp = mesh_degree(mesh, "data")
     ssm_ok = cfg.family in ("ssm", "hybrid") and \
         cfg.ssm_heads % tp == 0 and cfg.ssm_d_inner % tp == 0
-    embed_rule = "data" if cfg.d_model % dp == 0 and dp > 1 else None
-    if layout == "serve":
-        embed_rule = None
     return {
-        "embed": embed_rule,
+        "embed": "data" if cfg.d_model % dp == 0 and dp > 1 else None,
         "expert_embed": "data" if cfg.d_model % dp == 0 and dp > 1 else None,
         "vocab_rows": None,
         "embed_head": None,
@@ -46,19 +38,13 @@ def _rules(cfg: ModelConfig, mesh, layout: str = "train") -> dict:
     }
 
 
-def param_pspecs(cfg: ModelConfig, mesh, layout: str = "train"):
+def param_pspecs(cfg: ModelConfig, mesh):
     """PartitionSpec tree matching init_params/abstract_params structure."""
-    rules = _rules(cfg, mesh, layout)
+    rules = _rules(cfg, mesh)
     axes_tree = param_axes(cfg)
     return jax.tree_util.tree_map(
         lambda axes: P(*(rules[a] for a in axes)),
         axes_tree, is_leaf=lambda x: isinstance(x, tuple))
-
-
-def param_shardings(cfg: ModelConfig, mesh, layout: str = "train"):
-    return jax.tree_util.tree_map(
-        lambda spec: NamedSharding(mesh, spec),
-        param_pspecs(cfg, mesh, layout))
 
 
 def batch_pspecs(cfg: ModelConfig, mesh, *, global_batch: int):
@@ -74,63 +60,3 @@ def batch_pspecs(cfg: ModelConfig, mesh, *, global_batch: int):
     if cfg.family == "encdec":
         specs["frames"] = P(bspec, None, None)
     return specs
-
-
-def cache_pspecs(cfg: ModelConfig, mesh, *, batch: int):
-    """PartitionSpec tree matching init_cache structure for decode shapes.
-
-    Policy: shard cache batch over (pod,data) when divisible; KV heads over
-    'model' when divisible, else shard the sequence dim over 'model'.
-    batch==1 (long-context): sequence dim takes 'data' (and 'model' if the
-    heads don't divide) — flash-decode handles seq-sharded caches via its
-    online-softmax combine.
-    """
-    ba = batch_axes(mesh)
-    nb = 1
-    for a in ba:
-        nb *= mesh.shape[a]
-    tp = mesh_degree(mesh, "model")
-    b_ax = ba if ba and batch % nb == 0 else None
-    kv_ok = cfg.padded_kv_heads % tp == 0
-    seq_ax = []
-    if b_ax is None and mesh_degree(mesh, "data") > 1:
-        seq_ax.append("data")
-        if "pod" in mesh.axis_names:
-            seq_ax.insert(0, "pod")
-    if not kv_ok:
-        seq_ax.append("model")
-    seq_ax = tuple(seq_ax) if seq_ax else None
-    kv_spec = P(None, b_ax, seq_ax, "model" if kv_ok else None, None)
-
-    specs = {"pos": P()}
-    if cfg.family in ("dense", "vlm", "moe", "encdec"):
-        specs["k"] = kv_spec
-        specs["v"] = kv_spec
-        # int8 KV supported on self-attn caches of dense-style families
-        # (encdec keeps bf16: cross-attn cache is written once at prefill)
-        if cfg.kv_cache_dtype == "int8" and cfg.family != "encdec":
-            sc_spec = P(*tuple(kv_spec)[:4])
-            specs["k_scale"] = sc_spec
-            specs["v_scale"] = sc_spec
-    if cfg.family == "encdec":
-        specs["xk"] = kv_spec
-        specs["xv"] = kv_spec
-    if cfg.family in ("ssm", "hybrid"):
-        ssm_ok = cfg.ssm_heads % tp == 0 and cfg.ssm_d_inner % tp == 0
-        inner_ax = "model" if ssm_ok else None
-        specs["conv"] = P(None, b_ax, None, inner_ax)
-        specs["state"] = P(None, b_ax, inner_ax, None, None)
-    if cfg.family == "hybrid":
-        specs["k"] = kv_spec
-        specs["v"] = kv_spec
-    return specs
-
-
-def logits_pspec(cfg: ModelConfig, mesh, *, global_batch: int):
-    ba = batch_axes(mesh)
-    nb = 1
-    for a in ba:
-        nb *= mesh.shape[a]
-    bspec = ba if ba and global_batch % nb == 0 else None
-    tp = mesh_degree(mesh, "model")
-    return P(bspec, None, "model" if cfg.padded_vocab % tp == 0 else None)

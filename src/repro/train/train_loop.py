@@ -10,7 +10,7 @@ make_train_step(cfg, mesh, ...) returns a jitted (params, opt_state, batch)
   * optional int8 gradient compression with error feedback (DCN reduce).
 
 The same builder is used by the smoke tests (1-device mesh), the measured
-CPU runs, and the 512-device dry-run.
+CPU runs and the multi-device tests.
 """
 from __future__ import annotations
 

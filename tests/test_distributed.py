@@ -51,14 +51,12 @@ def test_moe_ep_matches_single_device_oracle():
 
 
 @pytest.mark.slow
-def test_train_and_serve_on_multipod_mesh():
+def test_train_on_multipod_mesh():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.base import ModelConfig
-        from repro.models import init_params, init_cache
         from repro.launch.mesh import make_mesh
         from repro.train.train_loop import make_train_step, init_train_state
-        from repro.serve.steps import make_serve_step, make_score_step
         mesh = make_mesh((2,2,2), ("pod","data","model"))
         base = dict(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
                     d_ff=128, vocab_size=256, head_pad_multiple=2,
@@ -77,16 +75,6 @@ def test_train_and_serve_on_multipod_mesh():
                 l0 = l0 or float(m["loss"])
             assert float(m["loss"]) < l0
             losses[fam] = float(m["loss"])
-        cfg = ModelConfig(name="d", family="dense", **base)
-        p = init_params(cfg, jax.random.PRNGKey(0))
-        serve = make_serve_step(cfg, mesh, batch=8, topk=8)
-        cache = init_cache(cfg, 8, 32)
-        ids, q, cache = serve(p, cache, jnp.zeros((8,), jnp.int32))
-        assert int(np.asarray(q).sum(-1)[0]) == 1 << 16
-        score = make_score_step(cfg, mesh, topk=8, s_block=16, global_batch=8)
-        ids, q = score(p, {"tokens": jax.random.randint(
-            jax.random.PRNGKey(3), (8, 32), 0, 256)})
-        assert ids.shape == (8, 32, 8)
         print("MESH_OK", losses)
     """)
     assert "MESH_OK" in out

@@ -126,3 +126,34 @@ def test_attention_impls_agree():
         c = attention_dense(q, k, v, causal=True, window=window)
         np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=2e-5)
         np.testing.assert_allclose(np.asarray(b), np.asarray(c), atol=2e-5)
+
+
+def test_cache_leaves_follow_the_lane_convention():
+    """ModelPredictor's lane programs (_reset, _snapshot, _restore,
+    _rollback) take 'pos' as (B,), leave 'xk'/'xv' (per-job conditioning)
+    whole, and index every other cache leaf's lanes on axis 1. Check that
+    every configuration's cache keeps to it: between batch 16 and batch 3
+    a leaf changes on exactly that axis."""
+    from repro.configs.registry import ARCH_IDS, get_config
+
+    def leaves(cfg, batch):
+        cache = jax.eval_shape(lambda: init_cache(cfg, batch, 128))
+        return jax.tree_util.tree_flatten_with_path(cache)[0]
+
+    for arch in ARCH_IDS + ["granite_4_0_h_small"]:
+        cfg = get_config(arch)
+        names = []
+        for (path, a), (_, b) in zip(leaves(cfg, 16), leaves(cfg, 3)):
+            name = path[-1].key
+            names.append(name)
+            if name in ("xk", "xv"):
+                assert cfg.family == "encdec", (arch, name)
+                continue
+            if name == "pos":
+                assert (a.shape, b.shape) == ((16,), (3,)), arch
+                continue
+            differ = [i for i, (m, n) in enumerate(zip(a.shape, b.shape))
+                      if m != n]
+            assert differ == [1] and a.shape[1] == 16, \
+                (arch, name, a.shape, b.shape)
+        assert "pos" in names, arch

@@ -4,8 +4,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.launch.mesh import make_mesh
-from repro.models.schema import abstract_params, param_axes, schema, Leaf
-from repro.sharding.specs import batch_pspecs, cache_pspecs, param_pspecs
+from repro.models.schema import abstract_params, schema, Leaf
+from repro.sharding.specs import batch_pspecs, param_pspecs
 
 
 def _fake_mesh():
@@ -36,7 +36,6 @@ def test_specs_rank_matches_shapes():
 def test_divisibility_policy():
     """Every sharded dim must divide by the production TP/DP degrees."""
     import numpy as np
-    from repro.launch.mesh import make_production_mesh
 
     class FakeMesh:
         shape = {"data": 16, "model": 16}
@@ -60,20 +59,6 @@ def test_divisibility_policy():
                 assert dim % deg == 0, (arch, leaf.shape, spec)
 
 
-def test_cache_specs_cover_cache_tree():
-    from repro.models import init_cache
-
-    class FakeMesh:
-        shape = {"data": 16, "model": 16}
-        axis_names = ("data", "model")
-
-    for arch in ARCH_IDS:
-        cfg = get_config(arch)
-        cache = jax.eval_shape(lambda c=cfg: init_cache(c, 16, 128))
-        specs = cache_pspecs(cfg, FakeMesh(), batch=16)
-        assert set(cache.keys()) == set(specs.keys()), arch
-
-
 def test_batch_unshardable_falls_back_to_replicated():
     class FakeMesh:
         shape = {"data": 16, "model": 16}
@@ -82,6 +67,3 @@ def test_batch_unshardable_falls_back_to_replicated():
     cfg = get_config("qwen3_14b")
     specs = batch_pspecs(cfg, FakeMesh(), global_batch=1)
     assert specs["tokens"][0] is None
-    c = cache_pspecs(cfg, FakeMesh(), batch=1)
-    assert c["k"][1] is None        # batch dim replicated
-    assert c["k"][2] is not None    # seq dim sharded instead

@@ -78,7 +78,7 @@ def test_own_text_more_compressible_than_human(trained_predictor):
 
 def test_prefill_estimate_close_to_exact(trained_predictor):
     """exact=False (prefill scoring) must produce ~the same SIZE as the
-    exact decode-path coder (it is the dry-run's prefill shape)."""
+    exact decode-path coder."""
     pred = trained_predictor
     data = encode(_gen_corpus(pred, 2048, seed=11))
     comp = LLMCompressor(pred, chunk_size=64, topk=32, decode_batch=8)
