@@ -77,6 +77,62 @@ def logits_to_cdf(logits, precision: int = DEFAULT_PRECISION) -> np.ndarray:
     return pmf_to_cdf(np.asarray(q))
 
 
+#: ids per block of the two-stage top-k selection (divides the 151,936,
+#: 102,400 and 100,352 vocabularies; one TPU lane row)
+TOPK_BLOCK = 128
+
+
+def topk_blocks(V: int, k: int) -> int:
+    """Blocks of ``TOPK_BLOCK`` ids that ``top_k`` splits a V-wide row
+    into, or 0 where it takes one ``lax.top_k`` over the row: the two
+    stages pay only when more than k blocks compete (V > k * 128)."""
+    nb = -(-V // TOPK_BLOCK)
+    return nb if nb > k else 0
+
+
+def _total_order_max(x: jnp.ndarray) -> jnp.ndarray:
+    """Max over the last axis in the float total order (-0.0 < +0.0), so a
+    block's maximum is never a -0.0 that hides a +0.0 of the same block:
+    each float maps to an int32 key that orders as the float does, and
+    the same map takes the largest key back."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    key = jnp.max(bits ^ ((bits >> 31) & 0x7FFFFFFF), axis=-1)
+    return jax.lax.bitcast_convert_type(key ^ ((key >> 31) & 0x7FFFFFFF),
+                                        jnp.float32)
+
+
+def top_k(x: jnp.ndarray, k: int):
+    """``lax.top_k`` of a float32 ``x`` over its last axis: the same values
+    and ids in the same order, ties to the lower id, without a TopK over
+    the whole vocabulary.
+
+    Where ``topk_blocks`` gives nb > 0 the row is viewed as nb blocks of
+    128 ids (padded with -inf at ids above every real one), the k blocks
+    with the largest maxima are kept, in ascending id order, and one TopK
+    over their k * 128 candidates picks the result. Exact, ties included:
+    every block that ranks above the block of a top-k element (by its
+    maximum, then its index) holds an element that ranks above it, so its
+    block is among the k kept; and candidates laid out in id order make
+    the second TopK's lower-position tie rule the lower-id rule."""
+    V = x.shape[-1]
+    nb = topk_blocks(V, k)
+    if not nb:
+        return jax.lax.top_k(x, k)
+    lead = x.shape[:-1]
+    rows = x.reshape(-1, V)
+    pad = nb * TOPK_BLOCK - V
+    if pad:
+        rows = jnp.pad(rows, ((0, 0), (0, pad)), constant_values=-jnp.inf)
+    blocks = rows.reshape(rows.shape[0], nb, TOPK_BLOCK)
+    _, bidx = jax.lax.top_k(_total_order_max(blocks), k)
+    bidx = jnp.sort(bidx, axis=-1)                            # id order
+    cand = jnp.take_along_axis(blocks, bidx[..., None], axis=1)
+    vals, pos = jax.lax.top_k(cand.reshape(-1, k * TOPK_BLOCK), k)
+    ids = (jnp.take_along_axis(bidx, pos // TOPK_BLOCK, axis=-1)
+           * TOPK_BLOCK + pos % TOPK_BLOCK)
+    return vals.reshape(lead + (k,)), ids.reshape(lead + (k,))
+
+
 def topk_quantized(logits: jnp.ndarray, k: int,
                    precision: int = DEFAULT_PRECISION,
                    temperature: float = 1.0):
@@ -89,7 +145,7 @@ def topk_quantized(logits: jnp.ndarray, k: int,
     Escape slot always has >= 1 quantum, so out-of-top-K tokens stay codable.
     """
     logits = logits.astype(jnp.float32) / temperature
-    top_vals, ids = jax.lax.top_k(logits, k)
+    top_vals, ids = top_k(logits, k)
     # Stable softmax over the full vocab, then renormalize the top-k slice.
     m = jnp.max(logits, axis=-1, keepdims=True)
     denom = jnp.sum(jnp.exp(logits - m), axis=-1, keepdims=True)

@@ -63,7 +63,8 @@ import numpy as np
 
 from repro import obs
 from repro.core import rans
-from repro.core.cdf import DEFAULT_PRECISION, full_cdf_jit, topk_cdf_jit
+from repro.core.cdf import (DEFAULT_PRECISION, full_cdf_jit, topk_blocks,
+                            topk_cdf_jit)
 from repro.core.compressor import ContainerError
 from repro.obs import ChunkDiagnostics, MetricsRegistry
 from .session import COMPRESS, ChunkTask
@@ -230,6 +231,9 @@ class SlotScheduler:
         self._c_compiles = self.registry.counter(
             "scheduler.step_compiles",
             "backend compiles and compile-cache loads inside a step")
+        self._c_blocked = self.registry.counter(
+            "cdf.topk_blocked",
+            "model steps whose top-k CDF took the two-stage selection")
         # per-slot diagnostics accrual (registry.enabled only). Decode
         # lanes: the coder's interval freq for position t lands in
         # _fbuf[b, t] (one fancy write per step, all log2 math deferred
@@ -422,6 +426,8 @@ class SlotScheduler:
                     topk_cdf_jit(logits, self.topk, self.precision),
                     logits, tel)
                 cdfs = cdfs.astype(np.int64)
+            if topk_blocks(logits.shape[-1], self.topk):
+                self._c_blocked.inc()
             with obs.span("coder.step", self.registry):
                 if dm.any():
                     slots = self._dec.get(cdfs, self.precision, dm)

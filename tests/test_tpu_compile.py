@@ -8,6 +8,8 @@ a v5e chip. The topology is described inside a fixture (never at import),
 so every test worker collects the same tests and only the worker that runs
 this file loads the TPU compiler.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -129,10 +131,38 @@ def test_decode_step_updates_cache_in_place(one_chip, cell):
     assert mem.temp_size_in_bytes < 0.01 * kv, mem.temp_size_in_bytes
 
 
+def _selection_operand_dims(hlo: str) -> list:
+    """Dims of every operand of a ``sort`` or ``TopK`` custom call in a
+    compiled HLO module's text."""
+    dims, out = {}, []
+    for line in hlo.splitlines():
+        head = re.match(r"\s*(?:ROOT )?%(\S+) = (.*)", line)
+        if not head:
+            continue
+        call = re.search(r"\s([\w-]+)\(([^)]*)\)", head[2])
+        if not call:
+            continue
+        dims[head[1]] = [int(d) for shp in re.findall(
+            r"\w\[([\d,]+)\]", head[2][:call.start()])
+            for d in shp.split(",")]
+        if call[1] == "sort" or 'custom_call_target="TopK"' in line:
+            out.extend(dims.get(op.strip().lstrip("%"), [])
+                       for op in call[2].split(",") if "%" in op)
+    return out
+
+
 def test_xla_topk_cdf_compiles(one_chip):
+    """The top-48 CDF program compiles, and at the Qwen3 cell's 64 lanes
+    of bfloat16 logits no sort or TopK reads an operand as wide as the
+    vocabulary: the selection runs over block maxima and 48 blocks."""
     from repro.core.cdf import topk_cdf_jit
     logits = jax.ShapeDtypeStruct((16, V), jnp.float32, sharding=one_chip)
     topk_cdf_jit.lower(logits, 48, 16).compile()
+    logits = jax.ShapeDtypeStruct((64, V), jnp.bfloat16, sharding=one_chip)
+    operands = _selection_operand_dims(
+        topk_cdf_jit.lower(logits, 48, 16).compile().as_text())
+    assert operands and all(operands), operands
+    assert not any(V in d for d in operands), operands
 
 
 def _kernel_case(name, s):
